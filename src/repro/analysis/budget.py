@@ -71,15 +71,9 @@ def _fastmix_bytes(shape, p, *, tracked: bool) -> int:
 
 
 def _apply_track_bytes(shape, p) -> int:
-    m, d, k = shape
-    mp = _round_up(m, 8)
-    kp = _round_up(k, 128)
-    bd = _round_up(min(p["block_d"], d), 8)
-    be = _round_up(min(p["block_e"], d), 128)
-    # docstring model mp*(bd*be + be*kp + 4*bd*kp) with the A/W tiles
-    # double buffered, + the resident (mp, mp) mixing matrix
-    words = mp * mp + mp * (2 * bd * be + 2 * be * kp + 4 * bd * kp)
-    return words * _WORD
+    """The kernel's own model (lazy import: jax)."""
+    from repro.kernels.fastmix import apply_track_vmem_words
+    return apply_track_vmem_words(*shape, p["block_d"], p["block_e"]) * _WORD
 
 
 def _gram_bytes(shape, p) -> int:
@@ -179,8 +173,12 @@ _NON_TILE_PARAMS = {"householder", "us"}
 
 def check_config(kernel: str, shape: Sequence[int],
                  params: Optional[Dict[str, int]] = None,
-                 device: str = "default") -> Tuple[int, int]:
-    """Returns ``(vmem_bytes, budget_bytes)`` for one configuration."""
+                 device: Optional[str] = None) -> Tuple[int, int]:
+    """Returns ``(vmem_bytes, budget_bytes)`` for one configuration, against
+    the budget of ``device`` (default: the device kind in use)."""
+    if device is None:
+        from repro.kernels.autotune import device_kind
+        device = device_kind()
     model = KERNEL_MODELS[kernel]
     p = dict(model.params)
     for key, val in (params or {}).items():
@@ -244,6 +242,10 @@ def run(cache_path: Optional[str] = None) -> PassResult:
         if not tile_params or not shape:
             # impl pins ("householder": 1) and timing-only entries
             result.skipped.append(f"no tile params in cache entry {key!r}")
+            continue
+        if device not in registry.VMEM_BUDGET_BYTES:
+            result.add("vmem-device", key, 0,
+                       f"no VMEM budget for device kind {device!r}")
             continue
         used, cap = check_config(kernel, shape, tile_params, device)
         result.checked += 1

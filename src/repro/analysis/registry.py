@@ -275,12 +275,13 @@ DEADCODE_QUARANTINE: Tuple[str, ...] = (
 # --------------------------------------------------------------------------
 #: Per-device-kind VMEM capacity in bytes.  Keys match
 #: :func:`repro.kernels.autotune.device_kind` strings (lower-case,
-#: underscore-separated); ``default`` covers unknown kinds.  ~16 MiB/core
-#: is the v4/v5e figure from the Pallas guide; CPU interpret-mode runs are
-#: held to the same budget so a CPU-tuned cache cannot pin a config that
-#: OOMs the day the job lands on a TPU.
+#: underscore-separated); a kind that is not listed is an error, not a
+#: default.  ~16 MiB/core is the v4/v5e scoped-VMEM figure from the Pallas
+#: guide; CPU interpret-mode runs are held to the same budget so a
+#: CPU-tuned cache cannot pin a config that OOMs the day the job lands on
+#: a TPU.
 VMEM_BUDGET_BYTES = {
-    "default": 16 * 1024 * 1024,
+    "cpu": 16 * 1024 * 1024,
     "tpu_v3": 16 * 1024 * 1024,
     "tpu_v4": 16 * 1024 * 1024,
     "tpu_v5_lite": 16 * 1024 * 1024,
@@ -303,10 +304,14 @@ REPRESENTATIVE_SHAPES: Tuple[Tuple[int, int, int], ...] = (
     (16, 1024, 16),
     (16, 2048, 32),
     (64, 4096, 32),
+    (50, 300, 5),            # the paper's w8a split (Section 5)
 )
 
 
 def vmem_budget(device: str) -> int:
     """Usable VMEM bytes for a device kind (capacity x safety factor)."""
-    cap = VMEM_BUDGET_BYTES.get(device, VMEM_BUDGET_BYTES["default"])
-    return int(cap * VMEM_SAFETY)
+    if device not in VMEM_BUDGET_BYTES:
+        raise ValueError(
+            f"no VMEM budget for device kind {device!r}; add it to "
+            f"VMEM_BUDGET_BYTES (known: {', '.join(VMEM_BUDGET_BYTES)})")
+    return int(VMEM_BUDGET_BYTES[device] * VMEM_SAFETY)

@@ -54,11 +54,11 @@ def _subjaxprs(v) -> Iterator[object]:
 
 
 def _dtypes(vars_, *, literals: bool = False):
-    import jax
     import jax.numpy as jnp
+    from jax.extend.core import Literal
     out = []
     for var in vars_:
-        if not literals and isinstance(var, jax.core.Literal):
+        if not literals and isinstance(var, Literal):
             continue
         aval = getattr(var, "aval", None)
         dt = getattr(aval, "dtype", None)
@@ -463,7 +463,6 @@ def run(names: Optional[Sequence[str]] = None) -> PassResult:
     """Trace and audit every registered entry point (or a name subset)."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     result = PassResult(name="tracecheck")
     for spec in TRACE_SPECS:
@@ -473,7 +472,7 @@ def run(names: Optional[Sequence[str]] = None) -> PassResult:
             unit = f"{spec.name}<{mode}>"
             try:
                 if mode == "f64":
-                    with enable_x64():
+                    with jax.enable_x64(True):
                         fn, args = spec.build(jnp.float64)
                         bad = audit_f64(jax.make_jaxpr(fn)(*args).jaxpr)
                 else:

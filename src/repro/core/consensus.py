@@ -544,7 +544,7 @@ class ConsensusEngine:
     def _mix_shard_map(self, S: jax.Array, rounds: int) -> jax.Array:
         fn = self._sharded_mix_cache.get(rounds)
         if fn is None:
-            from repro.runtime.compat import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
             mesh = _resolve_mesh(self.mesh, self.topology.m, self.axis)
             fn = jax.jit(shard_map(
@@ -816,7 +816,7 @@ class DynamicConsensusEngine:
         # rounds value, shared by all topologies
         fn = self._traced_cache.get(rounds)
         if fn is None:
-            from repro.runtime.compat import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
             from .gossip_shard import _dense_round, fastmix_local
             mesh = _resolve_mesh(self.mesh, self.schedule.topology_at(0).m,

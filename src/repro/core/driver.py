@@ -151,6 +151,9 @@ class IterationDriver:
     # loops) must not re-trace the T-step scan every time
     _run_cache: dict = dataclasses.field(
         default_factory=dict, init=False, repr=False)
+    # (jitted program, argument shapes) of the latest compiled launch
+    _last_launch: Optional[tuple] = dataclasses.field(
+        default=None, init=False, repr=False)
 
     def __post_init__(self):
         if (self.engine is None) == (self.dynamic is None):
@@ -165,6 +168,23 @@ class IterationDriver:
     def _diag_names(self) -> Tuple[str, ...]:
         return (self.diagnostics.names(self.step)
                 if self.diagnostics is not None else ())
+
+    def _launch(self, fn, *args):
+        """Call a cached jitted program, remembering its argument shapes
+        for :meth:`lower_last_launch`."""
+        self._last_launch = (fn, jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args))
+        return fn(*args)
+
+    def lower_last_launch(self) -> "jax.stages.Lowered":
+        """The lowering of the latest compiled launch (``scan``,
+        ``traced_scan`` or :meth:`run_batch`) at the shapes and dtypes it
+        ran with -- the program a serving path actually dispatched, e.g.
+        to see which Pallas kernels it holds."""
+        if self._last_launch is None:
+            raise RuntimeError("no compiled program has been launched yet")
+        fn, avals = self._last_launch
+        return fn.lower(*avals)
 
     def quantization_floor(self) -> float:
         """The engine's wire quantization floor (attached to diag events)."""
@@ -401,7 +421,7 @@ class IterationDriver:
         fn, warm = self._scan_fn(T, kind)
         with tracing.span("driver.launch", substrate="scan", T=int(T),
                           warm=warm):
-            carry, ys = fn(ops.array, W0, carry)
+            carry, ys = self._launch(fn, ops.array, W0, carry)
         S_hist, W_hist = ys[0], ys[1]
         diag = ys[2] if self.diagnostics is not None else None
         rounds = np.arange(1, T + 1, dtype=np.float32) * float(K)
@@ -415,7 +435,7 @@ class IterationDriver:
         fn, warm = self._traced_scan_fn(T, kind)
         with tracing.span("driver.launch", substrate="traced_scan", T=int(T),
                           warm=warm):
-            carry, ys = fn(ops.array, W0, carry, Ls, etas)
+            carry, ys = self._launch(fn, ops.array, W0, carry, Ls, etas)
         S_hist, W_hist = ys[0], ys[1]
         diag = ys[2] if self.diagnostics is not None else None
         rounds = np.arange(1, T + 1, dtype=np.float32) * float(self.step.rounds)
@@ -545,13 +565,13 @@ class IterationDriver:
                                       resume=resume)
             with tracing.span("driver.launch", substrate="vmap", T=int(T),
                               warm=warm):
-                out = fn(arr, W0, Ls, etas, *resumed)
+                out = self._launch(fn, arr, W0, Ls, etas, *resumed)
         else:
             fn, warm = self._batch_fn(T, kind, with_history, dynamic=False,
                                       resume=resume)
             with tracing.span("driver.launch", substrate="vmap", T=int(T),
                               warm=warm):
-                out = fn(arr, W0, *resumed)
+                out = self._launch(fn, arr, W0, *resumed)
         carry, hists, dvals = out
         diag = dvals if self.diagnostics is not None else None
         S, W, G_prev = carry[:3]
@@ -670,7 +690,7 @@ class IterationDriver:
         """
         import functools
         from jax.sharding import PartitionSpec as P
-        from repro.runtime.compat import shard_map
+        from jax import shard_map
 
         step = self.step
         if step.ef_wire:
@@ -715,7 +735,7 @@ class IterationDriver:
         """
         import functools
         from jax.sharding import PartitionSpec as P
-        from repro.runtime.compat import shard_map
+        from jax import shard_map
         from repro.kernels.fastmix import tracking_update
 
         step = self.step

@@ -18,8 +18,7 @@ the single shared :class:`~repro.core.step.PowerStep` on the local
 ``(1, d, k)`` slices, so the distributed runtime executes literally the
 same Alg. 1 body as the stacked simulator in :mod:`repro.core.algorithms`
 (bit-equivalence property-tested in tests/test_distributed.py and
-tests/test_driver.py).  ``shard_map`` itself comes from
-:mod:`repro.runtime.compat` so the code runs on every jax version.
+tests/test_driver.py).
 """
 from __future__ import annotations
 
@@ -275,12 +274,17 @@ class DistributedDeEPCA:
     def run(self, A: jax.Array, W0: jax.Array,
             schedule: Optional["TopologySchedule"] = None
             ) -> Tuple[jax.Array, jax.Array]:
-        """Runs T power iterations; returns (W_stack, S_stack)."""
+        """Runs T power iterations; returns (W_stack, S_stack).
+
+        Pass ``A`` as a host (NumPy) array to copy each agent's slice
+        straight to its own device; a ``jax.Array`` on one device is
+        resharded from there.
+        """
         m, d = self.topology.m, W0.shape[0]
         shard = NamedSharding(self.mesh, P(self.axis))
         rep = NamedSharding(self.mesh, P())
         W_stack = jax.device_put(
-            jnp.broadcast_to(W0, (m, d, self.k)), shard)
+            np.broadcast_to(np.asarray(W0), (m, d, self.k)), shard)
         # the step's full slot layout (zeroed W_prev for accelerated runs);
         # zeros_like keeps the agent-axis sharding of the seeded slots
         carry = self._step().normalize_carry((W_stack, W_stack, W_stack))

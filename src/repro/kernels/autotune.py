@@ -16,7 +16,9 @@ changes**.  Resolution precedence (checked per lookup, in order):
    fed by ``REPRO_FASTMIX_BLOCK_N`` through
    :mod:`repro.runtime.config`) — the one-flag experiment workflow keeps
    working and always wins;
-3. a cache entry for (kernel, device kind, shape bucket, dtype);
+3. a cache entry for (kernel, device kind, shape bucket, dtype), read
+   only when ``REPRO_AUTOTUNE_CACHE`` names a file — without it nothing
+   outside the checkout decides what a kernel compiles;
 4. the kernel's built-in default.
 
 The cache is *populated* offline by the benchmark sweeps
@@ -72,15 +74,11 @@ _MEMO: Dict[str, tuple] = {}
 _STAT_TTL = 1.0
 
 
-def default_cache_path() -> str:
-    """``RuntimeConfig.autotune_cache`` (i.e. ``$REPRO_AUTOTUNE_CACHE``)
-    or ``~/.cache/repro/autotune.json``."""
-    configured = runtime_config.get_config().autotune_cache
-    if configured:
-        return configured
-    base = os.environ.get("XDG_CACHE_HOME",
-                          os.path.join(os.path.expanduser("~"), ".cache"))
-    return os.path.join(base, "repro", "autotune.json")
+def default_cache_path() -> Optional[str]:
+    """``RuntimeConfig.autotune_cache`` (i.e. ``$REPRO_AUTOTUNE_CACHE``),
+    or ``None``: without it no cache file is read, so what a kernel
+    compiles is built from the repository's own defaults alone."""
+    return runtime_config.get_config().autotune_cache
 
 
 _DEVICE_KIND: Optional[str] = None
@@ -152,6 +150,8 @@ def _load_entries(path: str) -> Dict[str, dict]:
 
 def _entries(path: Optional[str] = None) -> Dict[str, dict]:
     p = path if path is not None else default_cache_path()
+    if p is None:
+        return {}
     now = time.monotonic()
     memo = _MEMO.get(p)
     if memo is not None and now - memo[2] < _STAT_TTL:
@@ -174,6 +174,10 @@ def record(kernel: str, shape: Iterable[int], dtype, params: dict, *,
     entry for (kernel, device, bucket, dtype); atomic write.  Returns the
     cache key written."""
     p = path if path is not None else default_cache_path()
+    if p is None:
+        raise ValueError(
+            f"no autotune cache file to record into: set {CACHE_ENV} or "
+            "pass path=")
     key = cache_key(kernel, shape, dtype, device=device)
     entries = dict(_entries(p))
     merged = dict(entries.get(key, {}))

@@ -30,6 +30,7 @@ def _gram_kernel(xi_ref, xj_ref, o_ref):
     xj = xj_ref[...]          # (bn, bd_j) panel of X
     o_ref[...] += jax.lax.dot_general(
         xi, xj, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
 
 

@@ -41,6 +41,7 @@ Three workloads share this entry point:
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
 
 import jax
@@ -106,7 +107,7 @@ def serve_pca(args) -> None:
     if wire in ("none", "fp32"):
         wire = None
     engine = ConsensusEngine.for_algorithm("deepca", topo, K=args.rounds,
-                                           backend="stacked",
+                                           backend="auto",
                                            wire_dtype=wire)
     if wire:
         ef = " + error feedback" if wire in EF_WIRE_DTYPES else ""
@@ -175,15 +176,17 @@ def serve_pca_stream(args) -> None:
         wire = None
     tracker = StreamingDeEPCA(
         k=k, T_tick=args.tick_iters, K=args.rounds, topology=topo,
-        backend="stacked", W0=stream.init_W0(),
+        backend="auto", W0=stream.init_W0(),
         policy=DriftPolicy(target=args.target),
         accelerated=args.accel or None, momentum=args.momentum,
         wire_dtype=wire, diagnostics=args.diag)
     print(f"[stream] m={m} d={d} k={k} rate={args.drift_rate}/tick "
           f"T_tick={args.tick_iters} K={args.rounds} target={args.target}")
     t0 = time.perf_counter()
-    with PrefetchIterator(stream.ticks(args.ticks), depth=2) as ticks:
-        for tick in ticks:
+    # the prefetch thread draws NumPy only; JAX work stays on this thread
+    with PrefetchIterator(stream.host_ticks(args.ticks), depth=2) as ticks:
+        for host in ticks:
+            tick = stream.on_device(host)
             r = tracker.tick(tick.ops, tick.U)
             flags = ("R" if r.restarted else "") + ("D" if r.drift else "")
             print(f"[stream] tick {r.tick:3d}: iters={r.iterations} "
@@ -195,7 +198,7 @@ def serve_pca_stream(args) -> None:
           f"({total / args.ticks:.1f} comm rounds/tick warm-started)")
 
     # --- 2. ragged one-shot requests through the dynamic-batching queue --
-    svc = PCAService(topo, T=args.iters, K=args.rounds, backend="stacked",
+    svc = PCAService(topo, T=args.iters, K=args.rounds, backend="auto",
                      policy=AdmissionPolicy(max_batch=args.max_batch,
                                             max_wait=args.max_wait),
                      diagnostics=args.diag)
@@ -224,10 +227,57 @@ def serve_pca_stream(args) -> None:
           f"mean={float(np.mean(tans)):.3e}")
 
 
-def serve_pca_fleet(args) -> None:
-    """Fleet workload: N drifting tenants, one program per shape bucket."""
-    from repro.core import erdos_renyi
+def fleet_tenant_mix(m: int, d: int, k: int, *, tenants: int,
+                     n_per_agent: int, rate: float, seed: int,
+                     heterogeneity: float = 1.0) -> dict:
+    """The fleet workload's tenants: ``tenant000..`` drifting streams with
+    10 distinct per-agent sample counts (``n_per_agent - 8`` in steps of
+    2), which the fleet's ``pad_n=16`` bucketing collapses onto two
+    compiled window programs."""
+    from repro.streaming import SlowRotationStream
+
+    def n_of(i: int) -> int:
+        return max(k + 2, n_per_agent - 8 + 2 * (i % 10))
+
+    return {f"tenant{i:03d}": SlowRotationStream(
+        m=m, d=d, k=k, n_per_agent=n_of(i), rate=rate,
+        heterogeneity=heterogeneity, seed=seed + i) for i in range(tenants)}
+
+
+@contextlib.contextmanager
+def fleet_ingest(streams: dict, ticks: int):
+    """Threaded per-tenant ingest: a
+    :class:`~repro.data.synthetic.MultiStreamPrefetcher` with one lane per
+    stream over its ``host_ticks(ticks)``, yielded for
+    :func:`ingest_tick`.  The lanes draw NumPy only.  While they run,
+    BLAS is held to one thread per caller: OpenBLAS's own pool, called
+    from many lanes at once, spins against itself (8 lanes drawing one
+    16-agent d=512 tick each: 34.6 s, against 0.69 s single-threaded, on
+    an 8-core host)."""
+    from threadpoolctl import threadpool_limits
     from repro.data.synthetic import MultiStreamPrefetcher
+    with threadpool_limits(limits=1, user_api="blas"), \
+            MultiStreamPrefetcher({tid: st.host_ticks(ticks)
+                                   for tid, st in streams.items()},
+                                  depth=2) as mux:
+        yield mux
+
+
+def ingest_tick(streams: dict, mux) -> dict:
+    """One fleet tick from :func:`fleet_ingest`'s lanes, put on the device
+    with its ground truth here, on the serving thread: JAX calls on the
+    lanes' threads would compile concurrently with this one and wait on
+    the persistent compile cache's file lock."""
+    return {tid: streams[tid].on_device(host)
+            for tid, host in mux.tick().items()}
+
+
+def serve_pca_fleet(args) -> dict:
+    """Fleet workload: N drifting tenants, one program per shape bucket.
+
+    Returns the fleet, the last tick's items and the tick latencies
+    (``warmup_ms``, ``steady_ms``) and steady-state cold launches."""
+    from repro.core import erdos_renyi
     from repro.streaming import DriftPolicy, SlowRotationStream, TrackerFleet
 
     m, d, k = args.m, args.d, args.k_top
@@ -238,36 +288,30 @@ def serve_pca_fleet(args) -> None:
         wire = None
     fleet = TrackerFleet(
         k=k, T_tick=args.tick_iters, K=args.rounds, topology=topo,
-        backend="stacked", policy=DriftPolicy(target=args.target),
+        backend="auto", policy=DriftPolicy(target=args.target),
         slots=args.slots, slo_ms=args.slo_ms,
         accelerated=args.accel or None, momentum=args.momentum,
         wire_dtype=wire, diagnostics=args.diag)
 
-    # mixed-shape tenant mix: 10 distinct per-agent sample counts that the
-    # pad_n=16 bucketing collapses onto two compiled window programs
-    def tenant_n(i: int) -> int:
-        return max(k + 2, args.n_per_agent - 8 + 2 * (i % 10))
-
-    streams = {}
-    for i in range(args.tenants):
-        tid = f"tenant{i:03d}"
-        streams[tid] = SlowRotationStream(
-            m=m, d=d, k=k, n_per_agent=tenant_n(i), rate=args.drift_rate,
-            seed=args.seed + i)
-        fleet.join(tid, streams[tid].init_W0(), n=tenant_n(i))
-    shapes = sorted({tenant_n(i) for i in range(args.tenants)})
+    streams = fleet_tenant_mix(m, d, k, tenants=args.tenants,
+                               n_per_agent=args.n_per_agent,
+                               rate=args.drift_rate, seed=args.seed)
+    for tid, st in streams.items():
+        fleet.join(tid, st.init_W0(), n=st.n_per_agent)
+    shapes = sorted({st.n_per_agent for st in streams.values()})
     print(f"[fleet] m={m} d={d} k={k} tenants={args.tenants} "
           f"n-shapes={shapes} T_tick={args.tick_iters} K={args.rounds}")
 
     half = max(1, args.ticks // 2)
     steady_cold = n_steady = 0
+    steady_ms = []
     t0 = time.perf_counter()
-    with MultiStreamPrefetcher(
-            {tid: st.ticks(args.ticks) for tid, st in streams.items()},
-            depth=2) as mux:
-        rep = fleet.tick(mux.tick())        # warm-up: compiles the buckets
+    with fleet_ingest(streams, args.ticks) as mux:
+        items = ingest_tick(streams, mux)
+        rep = fleet.tick(items)             # warm-up: compiles the buckets
+        warmup_ms = rep.latency_ms
         print(f"[fleet] warm-up tick: {rep.cold_launches} cold compiles, "
-              f"programs={fleet.program_count}")
+              f"programs={fleet.program_count} {warmup_ms:.1f} ms")
         t0 = time.perf_counter()
         for t in range(1, args.ticks):
             if t == half:
@@ -281,12 +325,15 @@ def serve_pca_fleet(args) -> None:
                     m=m, d=d, k=k, n_per_agent=n_old,
                     rate=args.drift_rate, seed=args.seed + 9999)
                 streams["joiner"] = joiner
-                mux.add("joiner", joiner.ticks(args.ticks - t), depth=2)
+                mux.add("joiner", joiner.host_ticks(args.ticks - t),
+                        depth=2)
                 fleet.join("joiner", joiner.init_W0(), n=n_old)
                 print(f"[fleet] tick {t}: churn — evicted {old}, "
                       f"admitted joiner (same bucket slot)")
-            rep = fleet.tick(mux.tick())
+            items = ingest_tick(streams, mux)
+            rep = fleet.tick(items)
             steady_cold += rep.cold_launches
+            steady_ms.append(rep.latency_ms)
             n_steady += 1
             worst = max(rep.tenants.values(), key=lambda r: r.stat)
             print(f"[fleet] tick {t}: windows={rep.windows} "
@@ -304,9 +351,12 @@ def serve_pca_fleet(args) -> None:
     print(f"[fleet] joins={s['joins']} leaves={s['leaves']} "
           f"restarts={s['restarts']} escalations={s['escalations']} "
           f"slo_breaches={s['slo_breaches']}")
+    return {"fleet": fleet, "last": items, "warmup_ms": warmup_ms,
+            "steady_ms": steady_ms, "steady_cold": steady_cold}
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
+    """The ``serve`` command line."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", default="lm",
                     choices=["lm", "pca", "pca-stream", "pca-fleet"])
@@ -384,10 +434,15 @@ def main() -> None:
                          "'chrome+jax:PATH' also wraps spans in "
                          "jax.profiler annotations, 'jax' annotates only "
                          "(default: $REPRO_TRACE if set)")
-    args = ap.parse_args()
+    return ap
+
+
+def main() -> None:
+    args = build_parser().parse_args()
 
     from repro.runtime import config as runtime_config
     from repro.runtime import diagnostics, telemetry, tracing
+    runtime_config.enable_compile_cache()
     cfg = runtime_config.get_config()
     spec = args.telemetry if args.telemetry is not None else cfg.telemetry
     sink = telemetry.sink_from_spec(spec)

@@ -54,7 +54,7 @@ def make_train_step_compressed(cfg: ArchConfig, opt: AdamW, mesh, topo, *,
     from jax.sharding import PartitionSpec as P
     from repro.compression.sharded import compress_local, init_state
     from repro.core.consensus import ConsensusEngine
-    from repro.runtime.compat import shard_map
+    from jax import shard_map
 
     m = int(np.prod(list(mesh.shape.values())))
     engine = ConsensusEngine.for_algorithm(

@@ -158,7 +158,7 @@ def _moe_shard_map(cfg: ArchConfig, p: Params, x: jax.Array, mesh, ep: str
     buffer across the mesh.
     """
     from jax.sharding import PartitionSpec as P
-    from repro.runtime.compat import shard_map
+    from jax import shard_map
     from . import partitioning as part
 
     b, s, d = x.shape

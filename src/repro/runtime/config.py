@@ -5,7 +5,8 @@ parsed and validated *here* — the rest of ``src/repro`` consumes the
 frozen :class:`RuntimeConfig` snapshot returned by :func:`get_config` and
 never touches ``os.environ`` directly (the ``env-config`` lint pass in
 :mod:`repro.analysis.lint` enforces this).  Likewise all ``jax.config``
-mutation (x64, platform, debug-nans, compile logging) goes through the
+mutation (x64, platform, debug-nans, compile logging, the persistent
+compile cache) goes through the
 first-class setters below, in the style of bayespec's
 ``elisa/util/config.py`` and the olmax launch scripts.
 
@@ -223,7 +224,8 @@ class RuntimeConfig:
     #: Measure-on-first-use autotuning (library calls never time-sweep
     #: unless opted in).
     autotune: bool = False
-    #: Autotune cache path; ``None`` -> ``$XDG_CACHE_HOME/repro/autotune.json``.
+    #: Autotune cache path; ``None`` -> no cache is read or written and
+    #: every kernel runs its built-in tiles.
     autotune_cache: Optional[str] = None
     #: Default telemetry sink spec; ``None`` -> no sink installed.
     telemetry: Optional[str] = None
@@ -381,12 +383,34 @@ def set_platform(platform: str = "cpu") -> None:
     for subprocesses too.
     """
     os.environ["JAX_PLATFORMS"] = platform
-    try:
-        import jax
-        jax.config.update("jax_platforms", platform)
-    except Exception:       # older jax spells it jax_platform_name
-        import jax
-        jax.config.update("jax_platform_name", platform)
+    import jax
+    jax.config.update("jax_platforms", platform)
+
+
+#: JAX's own variable naming its persistent compilation cache directory.
+JAX_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Called by entry points (``serve``, ``chip_smoke.py``), never at
+    import.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads
+    it and nothing is set here.  Otherwise the cache lives in
+    ``.jax_cache/`` at the checkout root, resolved from this file's path
+    (``<root>/src/repro/runtime/config.py``): the directory is part of
+    each entry's key, so it is never built from a temporary name, a pid
+    or the time.
+    """
+    configured = os.environ.get(JAX_CACHE_ENV)
+    if configured:
+        return configured
+    import jax
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__)))))
+    path = os.path.join(root, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def set_host_device_count(n: int) -> None:

@@ -23,8 +23,9 @@ entirely on the PR-3 step/driver seam (no new iteration loops):
 Entry points: ``python -m repro.launch.serve --workload pca-stream`` /
 ``--workload pca-fleet`` and ``benchmarks/bench_streaming.py``.
 """
-from .stream import (DriftingStream, EigengapShiftStream, SampleArrivalStream,
-                     SlowRotationStream, StreamTick, ragged_requests)
+from .stream import (DriftingStream, EigengapShiftStream, HostTick,
+                     SampleArrivalStream, SlowRotationStream, StreamTick,
+                     ragged_requests)
 from .tracker import (DriftPolicy, StreamingDeEPCA, TickReport,
                       concat_traces)
 from .service import AdmissionPolicy, PCAResponse, PCAService
@@ -33,7 +34,7 @@ from .fleet import (FleetTickReport, TenantReport, TrackerFleet,
 
 __all__ = [
     "DriftingStream", "SlowRotationStream", "EigengapShiftStream",
-    "SampleArrivalStream", "StreamTick", "ragged_requests",
+    "SampleArrivalStream", "StreamTick", "HostTick", "ragged_requests",
     "StreamingDeEPCA", "DriftPolicy", "TickReport", "concat_traces",
     "PCAService", "AdmissionPolicy", "PCAResponse",
     "TrackerFleet", "FleetTickReport", "TenantReport",

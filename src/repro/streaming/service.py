@@ -120,7 +120,7 @@ class PCAService:
     """
 
     def __init__(self, topology: Topology, *, T: int, K: int,
-                 algorithm: str = "deepca", backend: str = "stacked",
+                 algorithm: str = "deepca", backend: str = "auto",
                  policy: AdmissionPolicy = AdmissionPolicy(),
                  clock=time.monotonic, seed: int = 0,
                  diagnostics: Optional[object] = None):

@@ -27,6 +27,13 @@ Ground truth per tick comes from the *empirical* mean operator
 (:meth:`DriftingStream.truth_at` eigendecomposes ``mean_matrix()``), so
 diagnostics measure distance to the tick's actual answer, not to the
 generating model.
+
+Drawing a tick is NumPy work; putting it on the device and its ground
+truth are JAX work.  An ingest thread draws :meth:`DriftingStream.host_ticks`
+(NumPy only) and the serving thread turns each into a :class:`StreamTick`
+with :meth:`DriftingStream.on_device`: JAX calls on worker threads would
+compile concurrently with the serving thread and contend with it, e.g. on
+the persistent compile cache's file lock.
 """
 from __future__ import annotations
 
@@ -48,6 +55,13 @@ class StreamTick(NamedTuple):
     t: int
     ops: StackedOperators
     U: jax.Array                # (d, k) empirical top-k of mean_matrix()
+
+
+class HostTick(NamedTuple):
+    """One tick as drawn on the host, before any JAX call."""
+
+    t: int
+    data: np.ndarray            # (m, n, d) float32 samples
 
 
 def ragged_requests(m: int, d: int, k: int, count: int, *,
@@ -92,7 +106,7 @@ def _rotation(d: int, theta: float, seed: int) -> np.ndarray:
 class DriftingStream:
     """Deterministic tick-indexed problem stream (base class).
 
-    Subclasses implement :meth:`_make_ops`; this base owns per-tick
+    Subclasses implement :meth:`_make_data`; this base owns per-tick
     memoization, empirical ground truth, and iteration.  Shapes are
     constant across ticks so every tick rides one compiled driver program.
     """
@@ -128,14 +142,20 @@ class DriftingStream:
         self._evals = evals
 
     # ------------------------------------------------------------ plumbing
-    def ops_at(self, t: int) -> StackedOperators:
-        t = int(t)
+    def _host_data(self, t: int) -> np.ndarray:
+        """Tick ``t``'s float32 samples, drawn in NumPy only."""
         if t < 0:
             raise ValueError(f"stream tick must be >= 0, got {t}")
+        return np.asarray(self._make_data(t), dtype=np.float32)
+
+    def ops_at(self, t: int) -> StackedOperators:
+        t = int(t)
         ops = self._ops_memo.get(t)
         if ops is None:
-            ops = self._memo_put(self._ops_memo, t, self._make_ops(t),
-                                 self.memo_ticks)
+            ops = self._memo_put(
+                self._ops_memo, t,
+                StackedOperators(data=jnp.asarray(self._host_data(t))),
+                self.memo_ticks)
         return ops
 
     def truth_at(self, t: int) -> Tuple[jax.Array, jax.Array]:
@@ -156,6 +176,21 @@ class DriftingStream:
         for t in range(t0, t0 + n):
             yield self.tick(t)
 
+    def host_ticks(self, n: int, t0: int = 0) -> Iterator[HostTick]:
+        """Ticks ``t0 .. t0+n-1`` drawn in NumPy only: safe to iterate on
+        an ingest thread.  Bit-identical to :meth:`ticks`' data."""
+        for t in range(t0, t0 + n):
+            yield HostTick(t, self._host_data(t))
+
+    def on_device(self, host: HostTick) -> StreamTick:
+        """The :class:`StreamTick` of a :meth:`host_ticks` item: its device
+        array and ground truth, made on the calling thread."""
+        if host.t not in self._ops_memo:
+            self._memo_put(self._ops_memo, host.t,
+                           StackedOperators(data=jnp.asarray(host.data)),
+                           self.memo_ticks)
+        return self.tick(host.t)
+
     def init_W0(self, seed: Optional[int] = None) -> jax.Array:
         """A ``(d, k)`` orthonormal initialisation (the quickstart idiom)."""
         rng = np.random.default_rng(self.seed + 1 if seed is None else seed)
@@ -165,7 +200,7 @@ class DriftingStream:
 
     # --------------------------------------------------------- data drawing
     def _draw_agents(self, t: int, U: np.ndarray,
-                     evals: np.ndarray) -> StackedOperators:
+                     evals: np.ndarray) -> np.ndarray:
         """Per-agent samples from ``N(0, U diag(evals) U^T)`` with the
         :func:`~repro.core.operators.synthetic_spiked` heterogeneity model
         (agent-specific small rotations of the shared basis), rng-seeded per
@@ -178,9 +213,10 @@ class DriftingStream:
             Uj = np.linalg.qr(U + theta)[0]
             z = rng.standard_normal((n, d)) * np.sqrt(evals)
             data[j] = z @ Uj.T
-        return StackedOperators(data=jnp.asarray(data, dtype=jnp.float32))
+        return data
 
-    def _make_ops(self, t: int) -> StackedOperators:
+    def _make_data(self, t: int) -> np.ndarray:
+        """Tick ``t``'s ``(m, n, d)`` samples, in NumPy only."""
         raise NotImplementedError
 
 
@@ -197,7 +233,7 @@ class SlowRotationStream(DriftingStream):
 
     rate: float = 0.02
 
-    def _make_ops(self, t: int) -> StackedOperators:
+    def _make_data(self, t: int) -> np.ndarray:
         R = _rotation(self.d, self.rate * t, self.seed + 7)
         return self._draw_agents(t, R @ self._U0, self._evals)
 
@@ -218,7 +254,7 @@ class EigengapShiftStream(DriftingStream):
     shift_every: int = 4
     gap_shift: float = 0.5
 
-    def _make_ops(self, t: int) -> StackedOperators:
+    def _make_data(self, t: int) -> np.ndarray:
         regime = t // max(self.shift_every, 1)
         rng = np.random.default_rng((self.seed, 104_729, regime))
         U = np.linalg.qr(rng.standard_normal((self.d, self.d)))[0] \
@@ -269,11 +305,11 @@ class SampleArrivalStream(DriftingStream):
                                  2 * self.m * self.n_per_agent)
         return out
 
-    def _make_ops(self, t: int) -> StackedOperators:
+    def _make_data(self, t: int) -> np.ndarray:
         # window at tick t = global samples [t*arrivals, t*arrivals + n)
         lo = t * self.arrivals
         data = np.empty((self.m, self.n_per_agent, self.d), dtype=np.float64)
         for j in range(self.m):
             for i in range(self.n_per_agent):
                 data[j, i] = self._sample(j, lo + i)
-        return StackedOperators(data=jnp.asarray(data, dtype=jnp.float32))
+        return data

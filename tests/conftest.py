@@ -16,26 +16,8 @@ from __future__ import annotations
 
 import functools
 import inspect
-import os
 import sys
-import tempfile
 import types
-
-# ---------------------------------------------------------------------------
-# Hermetic autotune cache: kernels (and `qr_orth`'s impl pin) consult the
-# persistent cache at ~/.cache/repro/autotune.json via REPRO_AUTOTUNE_CACHE.
-# A developer who ran the README's `--record` sweeps would otherwise leak
-# machine-global tuning state (e.g. a per-bucket `householder` pin) into the
-# suite and silently change test numerics.  Point the whole session at a
-# throwaway path unless the caller explicitly pinned one; individual tests
-# (tests/test_autotune.py) still override per-test via monkeypatch.  This
-# goes through `configure()` (the env write stays in os.environ so
-# subprocess-spawning tests inherit it).
-from repro.runtime.config import ENV_AUTOTUNE_CACHE, configure  # noqa: E402
-
-if ENV_AUTOTUNE_CACHE not in os.environ:
-    configure(autotune_cache=os.path.join(
-        tempfile.mkdtemp(prefix="repro-test-autotune-"), "autotune.json"))
 
 try:
     import hypothesis  # noqa: F401  (real library present: nothing to do)

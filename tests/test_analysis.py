@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from repro.analysis import budget, deadcode, lint, registry, retrace, \
     tracecheck
 from repro.analysis.report import PassResult, Violation
+from repro.kernels import autotune
 
 FIXTURES = os.path.join(os.path.dirname(__file__), "fixtures", "analysis")
 
@@ -117,8 +118,7 @@ def test_tracecheck_clean_on_core_entry_points():
 
 
 def test_tracecheck_f64_audit_fires_on_narrowing():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         def leaky(x):
             y = x.astype(jnp.float32)          # the silent fidelity killer
             return (y @ y.T).astype(x.dtype)
@@ -152,8 +152,7 @@ def test_tracecheck_wire_audit_accepts_fp32_accumulation():
 
 def test_tracecheck_walks_into_scan_and_pallas():
     """The jaxpr walker must see inside lax.scan bodies."""
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         def leaky_scan(x):
             def body(c, _):
                 return (c.astype(jnp.float32).astype(x.dtype) + 1.0), None
@@ -271,7 +270,29 @@ def test_apply_track_default_tiles_shrink_at_large_m():
     bd, be = apply_track_default_tiles(64, 4096, 32)
     assert (bd, be) != (64, 256)
     words = apply_track_vmem_words(64, 4096, 32, bd, be)
-    assert words * 4 <= registry.vmem_budget("default")
+    assert words * 4 <= registry.vmem_budget(autotune.device_kind())
+
+
+def test_budget_rejects_old_apply_track_tiles_at_w8a_shape():
+    """Regression pin: the (32, 128) tiles the old model approved at the
+    paper's w8a shape need 18.12 MiB on a v5e (the compiler refuses them);
+    counting the resident output blocks rejects them, and the resolved
+    defaults fit."""
+    from repro.kernels.fastmix import apply_track_default_tiles
+    used, cap = budget.check_config(
+        "apply_track", (50, 300, 5), {"block_d": 32, "block_e": 128},
+        device="tpu_v5_lite")
+    assert used > cap
+    bd, be = apply_track_default_tiles(50, 300, 5)
+    used, cap = budget.check_config(
+        "apply_track", (50, 300, 5), {"block_d": bd, "block_e": be},
+        device="tpu_v5_lite")
+    assert used <= cap
+
+
+def test_vmem_budget_unknown_device_kind_is_an_error():
+    with pytest.raises(ValueError, match="no VMEM budget"):
+        registry.vmem_budget("tpu_v99")
 
 
 # =================================================================== deadcode

@@ -25,6 +25,24 @@ def cache(tmp_path, monkeypatch):
 
 
 # ------------------------------------------------------------- hit / miss
+def test_no_cache_file_is_read_without_env(tmp_path, monkeypatch):
+    """Without REPRO_AUTOTUNE_CACHE the built-in defaults decide: no file
+    outside the checkout is read, and recording needs an explicit path."""
+    monkeypatch.delenv(autotune.CACHE_ENV, raising=False)
+    monkeypatch.setenv("XDG_CACHE_HOME", str(tmp_path))
+    stray = tmp_path / "repro" / "autotune.json"
+    stray.parent.mkdir()
+    stray.write_text(json.dumps({"version": 1, "entries": {
+        autotune.cache_key("fastmix", (16, 8192), jnp.float32):
+            {"block_n": 1024}}}))
+    assert autotune.default_cache_path() is None
+    assert autotune.lookup("fastmix", "block_n", (16, 8192),
+                           jnp.float32) is None
+    with pytest.raises(ValueError, match=autotune.CACHE_ENV):
+        autotune.record("fastmix", (16, 8192), jnp.float32,
+                        {"block_n": 512})
+
+
 def test_lookup_miss_returns_none(cache):
     assert autotune.lookup("fastmix", "block_n", (16, 8192),
                            jnp.float32) is None

@@ -160,7 +160,7 @@ _SHARD_SCRIPT = textwrap.dedent("""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from repro.core import (ConsensusEngine, DistributedDeEPCA, ring,
                             erdos_renyi, sign_adjust, synthetic_spiked)
-    from repro.runtime.compat import shard_map
+    from jax import shard_map
 
     m, d, k, T, K = 8, 24, 3, 10, 5
     ops = synthetic_spiked(m, d, k, n_per_agent=32, seed=0)
@@ -258,6 +258,28 @@ def test_run_batch_matches_python_loop():
         ref = driver_d.run(problems[b], W0[b], T=T, t0=offs[b])
         np.testing.assert_array_equal(np.asarray(out_d.W[b]),
                                       np.asarray(ref.carry[1]))
+
+
+@pytest.mark.parametrize("substrate", ["scan", "run_batch"])
+def test_lower_last_launch_is_the_launched_program(substrate):
+    """The lowering ``lower_last_launch`` returns, compiled and called on
+    the launch's own arguments, reproduces the launch bit for bit."""
+    ops, _, W0 = _setup()
+    topo = erdos_renyi(8, p=0.6, seed=2)
+    driver = IterationDriver(
+        step=PowerStep.for_algorithm("deepca", 4),
+        engine=ConsensusEngine.for_algorithm("deepca", topo, K=4,
+                                             backend="stacked"))
+    with pytest.raises(RuntimeError, match="no compiled program"):
+        driver.lower_last_launch()
+    if substrate == "scan":
+        W = driver.run(ops, W0, T=5).carry[1]
+        args = (ops.array, W0, driver.step.init_carry(ops, W0))
+    else:
+        W = driver.run_batch([ops], W0[None], T=5).W
+        args = (ops.array[None], W0[None])
+    carry = driver.lower_last_launch().compile()(*args)[0]
+    np.testing.assert_array_equal(np.asarray(carry[1]), np.asarray(W))
 
 
 def test_run_batch_validation():

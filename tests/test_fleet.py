@@ -329,6 +329,31 @@ def test_ten_shape_mix_two_programs():
     assert fleet.program_count == 2
 
 
+def test_serve_fleet_workload_threaded_ingest():
+    """``serve --workload pca-fleet`` in process: ticks drawn on the
+    ingest lanes, two programs, no steady cold launch, the churn's joiner
+    served, and BLAS threading given back afterwards."""
+    from threadpoolctl import threadpool_info
+    from repro.launch import serve
+
+    def blas_threads():
+        return [p["num_threads"] for p in threadpool_info()
+                if p["user_api"] == "blas"]
+
+    before = blas_threads()
+    args = serve.build_parser().parse_args([
+        "--workload", "pca-fleet", "--tenants", "10", "--m", "6",
+        "--d", "16", "--k-top", "3", "--n-per-agent", "48", "--ticks", "4",
+        "--rounds", "3"])
+    out = serve.serve_pca_fleet(args)
+    fleet = out["fleet"]
+    assert fleet.program_count == 2 and out["steady_cold"] == 0
+    assert "joiner" in fleet.tenants
+    assert set(out["last"]) == set(fleet.tenants)
+    assert len(out["steady_ms"]) == 3
+    assert blas_threads() == before
+
+
 # ------------------------------------------------------------- guard rails
 def test_tick_requires_exact_tenant_cover():
     m, d, k = 6, 16, 3

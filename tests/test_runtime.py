@@ -121,6 +121,73 @@ def test_set_host_device_count_appends_never_clobbers(monkeypatch):
         config.set_host_device_count(0)
 
 
+# the compile cache is only ever turned on in child processes: the test
+# process itself keeps jax's default (off)
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_CACHE_SCRIPT = """
+import jax, jax.numpy as jnp
+from jax import monitoring
+hits = []
+monitoring.register_event_listener(
+    lambda name, **kw: hits.append(name)
+    if name == "/jax/compilation_cache/cache_hits" else None)
+from repro.runtime import config
+print("cache_dir", config.enable_compile_cache())
+if {solve}:
+    from repro.core import deepca, erdos_renyi
+    from repro.core.operators import synthetic_spiked
+    ops = synthetic_spiked(4, 16, 2, n_per_agent=8, seed=0)
+    W0 = jnp.linalg.qr(jax.random.normal(jax.random.PRNGKey(0), (16, 2)))[0]
+    jax.block_until_ready(deepca(ops, erdos_renyi(4, p=0.5, seed=0), W0,
+                                 k=2, T=3, K=2).W)
+print("jax_dir", jax.config.jax_compilation_cache_dir)
+print("hits", len(hits))
+"""
+
+
+def _cache_child(solve: bool, **env):
+    import subprocess
+    full = dict(os.environ, JAX_PLATFORMS="cpu",
+                PYTHONPATH=os.path.join(_ROOT, "src"))
+    full.pop(config.JAX_CACHE_ENV, None)
+    full.update(env)
+    out = subprocess.run([sys.executable, "-c",
+                          _CACHE_SCRIPT.format(solve=solve)],
+                         env=full, capture_output=True, text=True,
+                         timeout=300, check=True).stdout
+    return dict(line.split(" ", 1) for line in out.splitlines()
+                if line.split(" ", 1)[0] in ("cache_dir", "jax_dir", "hits"))
+
+
+def test_compile_cache_env_dir_is_hit_by_a_second_process(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, an entry point's second run in a
+    fresh process reads what the first wrote there, and nothing lands in
+    the checkout's own ``.jax_cache/``."""
+    repo_cache = os.path.join(_ROOT, ".jax_cache")
+    before = (sorted(os.listdir(repo_cache)) if os.path.isdir(repo_cache)
+              else None)
+    env = {config.JAX_CACHE_ENV: str(tmp_path / "jc"),
+           # cache every program, however fast it compiles
+           "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0"}
+    first = _cache_child(True, **env)
+    second = _cache_child(True, **env)
+    assert first["cache_dir"] == second["cache_dir"] == str(tmp_path / "jc")
+    assert first["hits"] == "0"
+    assert os.listdir(tmp_path / "jc")
+    assert int(second["hits"]) > 0
+    after = (sorted(os.listdir(repo_cache)) if os.path.isdir(repo_cache)
+             else None)
+    assert after == before
+
+
+def test_compile_cache_defaults_to_checkout_root():
+    """Unset, the cache goes to ``.jax_cache/`` at the checkout root,
+    resolved from the package path (nothing is compiled here)."""
+    got = _cache_child(False)
+    assert got["cache_dir"] == got["jax_dir"] == os.path.join(_ROOT,
+                                                              ".jax_cache")
+
+
 def test_describe_is_json_serializable_provenance(monkeypatch):
     monkeypatch.setenv(config.ENV_QR_IMPL, "cholqr2")
     d = config.describe()

@@ -229,6 +229,39 @@ def test_streams_are_deterministic_and_constant_shape():
             assert a.ops_at(t).data.shape == (4, 10, 12)
 
 
+@pytest.mark.parametrize("cls,kw", [
+    (SlowRotationStream, dict(rate=0.05)),
+    (EigengapShiftStream, dict(shift_every=2)),
+    (SampleArrivalStream, dict(arrivals=5)),
+], ids=lambda v: getattr(v, "__name__", ""))
+def test_host_ticks_touch_no_jax_and_match_ticks(cls, kw, monkeypatch):
+    """``host_ticks`` (what ingest threads iterate) draws in NumPy only;
+    ``on_device`` then yields the very tick ``ticks`` does."""
+    from repro.streaming import stream as stream_mod
+    a = cls(m=4, d=12, k=2, n_per_agent=10, seed=3, **kw)
+    b = cls(m=4, d=12, k=2, n_per_agent=10, seed=3, **kw)
+
+    class NoJax:
+        def __getattr__(self, name):
+            raise AssertionError(f"host_ticks called jnp.{name}")
+
+    def no_truth(*_):
+        raise AssertionError("host_ticks computed the ground truth")
+
+    with monkeypatch.context() as mp:
+        mp.setattr(stream_mod, "jnp", NoJax())
+        mp.setattr(stream_mod, "top_k_eigvecs", no_truth)
+        hosts = list(a.host_ticks(3, t0=1))
+    for host, want in zip(hosts, b.ticks(3, t0=1)):
+        assert isinstance(host.data, np.ndarray)
+        assert host.data.dtype == np.float32 and host.t == want.t
+        got = a.on_device(host)
+        np.testing.assert_array_equal(host.data, np.asarray(want.ops.data))
+        np.testing.assert_array_equal(np.asarray(got.ops.data),
+                                      np.asarray(want.ops.data))
+        np.testing.assert_array_equal(np.asarray(got.U), np.asarray(want.U))
+
+
 def test_sample_arrival_windows_overlap():
     """Tick t and t+1 share the bit-identical overlapping samples."""
     s = SampleArrivalStream(m=3, d=8, k=2, n_per_agent=8, arrivals=3, seed=1)

@@ -7,7 +7,11 @@ import pytest
 
 _ENV = dict(os.environ,
             PYTHONPATH=os.path.abspath(os.path.join(
-                os.path.dirname(__file__), "..", "src")))
+                os.path.dirname(__file__), "..", "src")),
+            # serve turns the persistent compile cache on (in the
+            # checkout's .jax_cache/ unless JAX_COMPILATION_CACHE_DIR is
+            # set); the tests keep it off
+            JAX_ENABLE_COMPILATION_CACHE="false")
 
 
 def _run(*args, timeout=1200):

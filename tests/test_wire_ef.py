@@ -65,7 +65,7 @@ def test_int8_quantize_f64_parity():
     f64 round-trips of the same values agree to f32 round-off."""
     rng = np.random.default_rng(1)
     vals = rng.standard_normal((4, 16))
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         x64 = jnp.asarray(vals, jnp.float64)
         q64 = quantize_wire(x64, "int8")
         assert q64.dtype == jnp.float64
