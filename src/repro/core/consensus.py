@@ -88,6 +88,7 @@ import jax.numpy as jnp
 
 from .mixing import fastmix, fastmix_eta, fastmix_wire, fastmix_wire_ef, \
     naive_mix
+from .step import SCOPE_APPLY, SCOPE_MIX
 from .topology import Topology
 
 BACKENDS = ("auto", "stacked", "pallas", "shard_map")
@@ -521,13 +522,16 @@ class ConsensusEngine:
                     f"leading (agent) axis {S.shape[0]} != topology m="
                     f"{self.topology.m}")
             from repro.kernels.fastmix import apply_track_fused
-            S_new, G = apply_track_fused(
-                ops.dense, W, S, G_prev, self._L(jnp.float32), self.eta, r,
-                interpret=self.interpret is True,
-                wire_bf16=self.wire_dtype is not None)
+            with jax.named_scope(SCOPE_MIX):
+                S_new, G = apply_track_fused(
+                    ops.dense, W, S, G_prev, self._L(jnp.float32), self.eta,
+                    r, interpret=self.interpret is True,
+                    wire_bf16=self.wire_dtype is not None)
             return S_new.astype(S.dtype), G.astype(S.dtype)
-        G = ops.apply(W)
-        return self.mix_track(S, G, G_prev, rounds=rounds), G
+        with jax.named_scope(SCOPE_APPLY):
+            G = ops.apply(W)
+        with jax.named_scope(SCOPE_MIX):
+            return self.mix_track(S, G, G_prev, rounds=rounds), G
 
     def _mix_fused(self, S: jax.Array, rounds: int) -> jax.Array:
         dtype = jnp.float64 if S.dtype == jnp.float64 else jnp.float32
@@ -801,13 +805,17 @@ class DynamicConsensusEngine:
                 and S.dtype != jnp.float64
                 and _use_pallas_kernel(self.interpret)):
             from repro.kernels.fastmix import apply_track_fused
-            S_new, G = apply_track_fused(
-                ops.dense, W, S, G_prev, L.astype(jnp.float32), eta, r,
-                interpret=self.interpret is True,
-                wire_bf16=self.wire_dtype is not None)
+            with jax.named_scope(SCOPE_MIX):
+                S_new, G = apply_track_fused(
+                    ops.dense, W, S, G_prev, L.astype(jnp.float32), eta, r,
+                    interpret=self.interpret is True,
+                    wire_bf16=self.wire_dtype is not None)
             return S_new.astype(S.dtype), G.astype(S.dtype)
-        G = ops.apply(W)
-        return self.mix_track_traced(S, G, G_prev, L, eta, rounds=rounds), G
+        with jax.named_scope(SCOPE_APPLY):
+            G = ops.apply(W)
+        with jax.named_scope(SCOPE_MIX):
+            return (self.mix_track_traced(S, G, G_prev, L, eta,
+                                          rounds=rounds), G)
 
     def _mix_shard_map_traced(self, S, L, eta, rounds: int):
         # the dense all_gather round is the only lowering valid for EVERY

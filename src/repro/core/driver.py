@@ -533,7 +533,8 @@ class IterationDriver:
         if step.increasing:
             raise ValueError("increasing rounds cannot be batched "
                              "(round counts vary per problem step)")
-        kind, arr = self._stack_problems(ops_batch)
+        with tracing.span("driver.stack"):
+            kind, arr = self._stack_problems(ops_batch)
         B = arr.shape[0]
         W0 = jnp.asarray(W0)
         if W0.ndim == 2:

@@ -41,6 +41,13 @@ import jax.numpy as jnp
 #: than assuming length 3.
 Carry = Tuple[jax.Array, ...]
 
+#: ``jax.named_scope`` names of the step's three stages.  Every device op
+#: a stage lowers to carries its scope in its metadata path, so a profile
+#: of any substrate splits device time into apply, mix and orth.
+SCOPE_APPLY = "deepca.apply"
+SCOPE_MIX = "deepca.mix"
+SCOPE_ORTH = "deepca.orth"
+
 
 def sign_adjust(W: jax.Array, W0: jax.Array) -> jax.Array:
     """Alg. 2: flip column signs of W so <W[:,i], W0[:,i]> >= 0."""
@@ -253,18 +260,24 @@ class PowerStep:
         W_prev = extras[0] if self.accelerated else None
         ef = extras[-1] if self.ef_wire else None
         if apply_mix is not None and self.track and ef is None:
+            # the engine names its own stages (apply, or one fused kernel
+            # under mix)
             S_new, G = apply_mix(S, W, G_prev)    # fused Eqns. apply+(3.1)+(3.2)
         else:
-            G = apply_fn(W)                   # A_j W_j^t   (local compute)
-            if ef is None:
-                S_new = mix(S, G, G_prev)     # Eqns. (3.1)+(3.2) fused in mix
-            else:
-                S_new, ef = mix(S, G, G_prev, ef)   # + EF residual update
-        # Accelerated variant: momentum acts only on the QR *input* — the
-        # carried S stays the gossiped iterate, so subspace tracking and
-        # the consensus invariant are exactly the unaccelerated ones.
-        Y = S_new - self.momentum * W_prev if self.accelerated else S_new
-        W_new = sign_adjust(qr_orth(Y), W0)       # Eqn. (3.3) + Alg. 2
+            with jax.named_scope(SCOPE_APPLY):
+                G = apply_fn(W)               # A_j W_j^t   (local compute)
+            with jax.named_scope(SCOPE_MIX):
+                if ef is None:
+                    S_new = mix(S, G, G_prev)   # Eqns. (3.1)+(3.2) in mix
+                else:
+                    S_new, ef = mix(S, G, G_prev, ef)   # + EF residual
+        with jax.named_scope(SCOPE_ORTH):
+            # Accelerated variant: momentum acts only on the QR *input* —
+            # the carried S stays the gossiped iterate, so subspace
+            # tracking and the consensus invariant are exactly the
+            # unaccelerated ones.
+            Y = S_new - self.momentum * W_prev if self.accelerated else S_new
+            W_new = sign_adjust(qr_orth(Y), W0)   # Eqn. (3.3) + Alg. 2
         new_extras = ((W,) if self.accelerated else ()) \
             + ((ef,) if self.ef_wire else ())
         return (S_new, W_new, G) + new_extras, (S_new, W_new)

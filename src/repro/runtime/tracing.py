@@ -5,7 +5,8 @@ other two); this module owns the *where-did-the-time-go* prong:
 
 * :func:`span` — a nested context manager placed at the structural
   boundaries of a run (serve request -> ``driver.run`` -> warm/cold
-  launch -> profile stages, service batch launches, streaming ticks).
+  launch -> profile stages; the service's submit, poll and launch with
+  the launch's pad, complement and stack steps; streaming ticks).
   With no tracer installed it is a no-op costing one attribute read, so
   the instrumentation can live permanently in the hot paths.
 * :class:`ChromeTracer` — collects completed spans as Chrome trace
@@ -13,8 +14,10 @@ other two); this module owns the *where-did-the-time-go* prong:
   writes a ``{"traceEvents": [...]}`` JSON file that loads directly in
   Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``.
 * an opt-in `jax.profiler` hookup — when enabled, every span also opens
-  a ``jax.profiler.TraceAnnotation`` so spans line up with XLA's own
-  activity when a device profile is captured separately.
+  a ``jax.profiler.TraceAnnotation`` carrying the span's attributes, so a
+  profile captured with ``jax.profiler.start_trace`` holds the spans on
+  the device trace's clock.  ``ChromeTracer`` timestamps come from the
+  host's ``perf_counter`` and cannot be overlaid on a device profile.
 
 Spans additionally emit ``span`` telemetry events (name, ``dur_us``,
 ``depth``) through :mod:`repro.runtime.telemetry` when a sink is active,
@@ -158,8 +161,9 @@ def span(name: str, **attrs):
     """Record a nested wall-clock span around the enclosed block.
 
     No-op (one global read) when no tracer is installed.  ``attrs`` must
-    be JSON-scalar-ish; they land in the trace event's ``args`` and the
-    ``span`` telemetry event's fields.
+    be JSON-scalar-ish; they land in the trace event's ``args``, the
+    ``span`` telemetry event's fields and, under a tracer with
+    ``jax_annotations``, the profiler event's stats.
     """
     tracer = _TRACER
     if tracer is None:
@@ -171,7 +175,8 @@ def span(name: str, **attrs):
     if tracer.jax_annotations:
         try:
             from jax.profiler import TraceAnnotation
-            annotation = TraceAnnotation(name)
+            annotation = TraceAnnotation(
+                name, **{k: _jsonable(v) for k, v in attrs.items()})
             annotation.__enter__()
         except Exception:  # profiler unavailable: spans still record
             annotation = None

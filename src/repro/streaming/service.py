@@ -165,12 +165,15 @@ class PCAService:
         ops, W0 = p.ops, p.W0
         padded = False
         if kind == "data" and ops.data.shape[1] != n_pad:
-            ops = StackedOperators(data=jnp.pad(
-                ops.data, ((0, 0), (0, n_pad - ops.data.shape[1]), (0, 0))))
+            with tracing.span("service.pad", request=p.request_id):
+                ops = StackedOperators(data=jnp.pad(
+                    ops.data,
+                    ((0, 0), (0, n_pad - ops.data.shape[1]), (0, 0))))
             padded = True
         if W0.shape[1] != k_pad:
-            W0 = jnp.concatenate(
-                [W0, self._complement(W0, k_pad - W0.shape[1])], axis=1)
+            with tracing.span("service.complement", request=p.request_id):
+                W0 = jnp.concatenate(
+                    [W0, self._complement(W0, k_pad - W0.shape[1])], axis=1)
             padded = True
         if padded:
             self.stats["padded_requests"] += 1
@@ -198,14 +201,15 @@ class PCAService:
             raise ValueError(
                 f"request has m={ops.m} agents; this service's fleet is "
                 f"m={self.m}")
-        key = self.bucket_of(ops, W0.shape[1])
         rid = self._next_id
-        self._next_id += 1
-        self._buckets.setdefault(key, []).append(
-            _Pending(rid, ops, W0, self._clock()))
-        self.stats["requests"] += 1
-        if len(self._buckets[key]) >= self.policy.max_batch:
-            self._launch(key)
+        with tracing.span("service.submit", request=rid):
+            key = self.bucket_of(ops, W0.shape[1])
+            self._next_id += 1
+            self._buckets.setdefault(key, []).append(
+                _Pending(rid, ops, W0, self._clock()))
+            self.stats["requests"] += 1
+            if len(self._buckets[key]) >= self.policy.max_batch:
+                self._launch(key)
         return rid
 
     def poll(self, now: Optional[float] = None) -> int:
@@ -213,11 +217,12 @@ class PCAService:
         returns the number of launches."""
         now = self._clock() if now is None else now
         n = 0
-        for key in list(self._buckets):
-            q = self._buckets[key]
-            if q and now - q[0].arrived >= self.policy.max_wait:
-                self._launch(key)
-                n += 1
+        with tracing.span("service.poll"):
+            for key in list(self._buckets):
+                q = self._buckets[key]
+                if q and now - q[0].arrived >= self.policy.max_wait:
+                    self._launch(key)
+                    n += 1
         return n
 
     def flush(self) -> int:
@@ -244,22 +249,27 @@ class PCAService:
         now = self._clock()
         B = len(q)
         B_pad = _pow2_at_least(B, self.policy.max_batch)
-        padded = [self._pad_request(p, key) for p in q]
-        # pad the batch axis with copies of the first problem so every
-        # launch in this bucket uses one of log2(max_batch) program shapes
-        while len(padded) < B_pad:
-            padded.append(padded[0])
-        problems = [ops for ops, _ in padded]
-        W0 = jnp.stack([w for _, w in padded])
         sig = (key, B_pad)
         warm = sig in self._signatures
+        launch = self.stats["batches"]
         self.stats["warm_launches" if warm else "cold_launches"] += 1
         self._signatures.add(sig)
         self.stats["batches"] += 1
-        telemetry.emit("service.launch", bucket=str(key), batch=B,
-                       batch_padded=B_pad, warm=warm)
-        with tracing.span("service.launch", bucket=str(key), batch=B_pad,
-                          warm=warm):
+        if telemetry.enabled():
+            telemetry.emit("service.launch", bucket=str(key), batch=B,
+                           batch_padded=B_pad, warm=warm)
+        attrs = (dict(launch=launch, batch=B, batch_padded=B_pad, warm=warm,
+                      bucket=str(key)) if tracing.enabled() else {})
+        with tracing.span("service.launch", **attrs):
+            padded = [self._pad_request(p, key) for p in q]
+            # pad the batch axis with copies of the first problem so every
+            # launch in this bucket uses one of log2(max_batch) program
+            # shapes
+            while len(padded) < B_pad:
+                padded.append(padded[0])
+            problems = [ops for ops, _ in padded]
+            with tracing.span("service.stack"):
+                W0 = jnp.stack([w for _, w in padded])
             out = self.driver.run_batch(problems, W0, T=self.T)
         for b, p in enumerate(q):
             k = p.W0.shape[1]

@@ -527,3 +527,131 @@ def test_jax_annotation_spans_still_record(tmp_path):
     finally:
         tracing.set_tracer(None)
     assert len(tracer) == 1
+
+
+def test_jax_spans_pass_attributes_to_trace_annotation(monkeypatch):
+    """Under a ``jax`` tracer a span's attributes reach the profiler's
+    annotation (made JSON-scalar), so ids land in the device trace."""
+    opened = []
+
+    class Annotation:
+        def __init__(self, name, **kwargs):
+            opened.append((name, kwargs))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    tracing.set_tracer(tracing.tracer_from_spec("jax"))
+    with tracing.span("service.launch", launch=3, warm=True,
+                      bucket=("data", 4)):
+        pass
+    assert opened == [("service.launch",
+                       {"launch": 3, "warm": True,
+                        "bucket": "('data', 4)"})]
+
+
+def _span_tree(events):
+    """Rebuild the span tree from ``span`` telemetry events, which arrive
+    as spans close (children first), each with its nesting depth."""
+    open_children = {}
+    for e in events:
+        node = (e["name"],
+                {k: v for k, v in e.items()
+                 if k not in ("event", "name", "dur_us", "depth")},
+                open_children.pop(e["depth"] + 1, []))
+        open_children.setdefault(e["depth"], []).append(node)
+    return open_children.pop(0, [])
+
+
+def _service(clock):
+    from repro.core import erdos_renyi
+    from repro.streaming import AdmissionPolicy, PCAService
+    return PCAService(erdos_renyi(4, p=0.6, seed=0), T=2, K=2,
+                      backend="stacked", clock=clock,
+                      policy=AdmissionPolicy(max_batch=2, max_wait=0.5,
+                                             pad_n=16, pad_k=4))
+
+
+def _service_request(seed, m=4, n=10, d=8, k=3):
+    from repro.core.operators import StackedOperators
+    rng = np.random.default_rng(seed)
+    ops = StackedOperators(data=jnp.asarray(rng.standard_normal((m, n, d)),
+                                            jnp.float32))
+    W0 = jnp.asarray(np.linalg.qr(rng.standard_normal((d, k)))[0],
+                     jnp.float32)
+    return ops, W0
+
+
+def test_service_spans_nest_launch_work_under_submit_and_poll(tmp_path):
+    """A full bucket's launch nests in the submit that filled it, a forced
+    launch in the poll; each launch covers the pads, completions and
+    stacks before the dispatch, with the request and launch ids."""
+    clock = {"now": 0.0}
+    svc = _service(lambda: clock["now"])
+    tracing.set_tracer(tracing.ChromeTracer(str(tmp_path / "s.json")))
+    with telemetry.capture() as rec:
+        r0 = svc.submit(*_service_request(0))
+        r1 = svc.submit(*_service_request(1))     # fills the bucket
+        r2 = svc.submit(*_service_request(2))
+        clock["now"] = 1.0
+        assert svc.poll() == 1                    # max_wait forces it
+    assert all(svc.result(r) is not None for r in (r0, r1, r2))
+    bucket = str(svc.bucket_of(_service_request(0)[0], 3))
+
+    def launch(lid, requests, batch_padded):
+        # each batch shape launches once, so the service counts both
+        # launches cold; the driver's ``warm`` is a hit in its per-T
+        # program cache, so its second launch is warm
+        work = [child for r in requests for child in (
+            ("service.pad", {"request": r}, []),
+            ("service.complement", {"request": r}, []))]
+        return ("service.launch",
+                {"launch": lid, "batch": len(requests),
+                 "batch_padded": batch_padded, "warm": False,
+                 "bucket": bucket},
+                work + [("service.stack", {}, []),
+                        ("driver.stack", {}, []),
+                        ("driver.launch",
+                         {"substrate": "vmap", "T": 2, "warm": lid > 0},
+                         [])])
+
+    assert _span_tree(rec.of("span")) == [
+        ("service.submit", {"request": r0}, []),
+        ("service.submit", {"request": r1},
+         [launch(0, [r0, r1], 2)]),
+        ("service.submit", {"request": r2}, []),
+        ("service.poll", {}, [launch(1, [r2], 1)]),
+    ]
+
+
+def test_service_records_no_spans_without_tracer(monkeypatch):
+    opened = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        lambda *a, **k: opened.append(a))
+    clock = {"now": 0.0}
+    svc = _service(lambda: clock["now"])
+    with telemetry.capture() as rec:
+        svc.submit(*_service_request(0))
+        svc.submit(*_service_request(1))
+        svc.submit(*_service_request(2))
+        clock["now"] = 1.0
+        svc.poll()
+    assert rec.of("span") == [] and opened == []
+    # the launch events still carry their attributes for a live sink
+    assert [e["batch"] for e in rec.of("service.launch")] == [2, 1]
+
+
+def test_run_batch_program_names_the_step_stages():
+    """The served program's ops carry the apply, mix and orth scopes in
+    their metadata, so a device profile splits the step by stage."""
+    from repro.core.step import SCOPE_APPLY, SCOPE_MIX, SCOPE_ORTH
+    svc = _service(lambda: 0.0)
+    svc.submit(*_service_request(0))
+    svc.submit(*_service_request(1))
+    text = svc.driver.lower_last_launch().as_text(debug_info=True)
+    for scope in (SCOPE_APPLY, SCOPE_MIX, SCOPE_ORTH):
+        assert scope in text, scope
