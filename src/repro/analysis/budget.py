@@ -86,10 +86,10 @@ def _gram_bytes(shape, p) -> int:
 
 
 def _cholqr_bytes(shape, p) -> int:
-    # gram kernel under the `cholqr` autotune name on (d, k) factors:
-    # panels are (block_n <= d rows, block_d <= k cols)
-    d, k = shape[-2], shape[-1]
-    return _gram_bytes((d, k), p)
+    """CholeskyQR2's ``gram_tall`` kernel on ``(n, d, k)`` factors: the
+    kernel's own model (lazy import: jax)."""
+    from repro.kernels.gram import gram_tall_vmem_words
+    return gram_tall_vmem_words(*shape[1:], p["block_f"], p["block_d"]) * _WORD
 
 
 def _power_matmul_bytes(shape, p) -> int:
@@ -112,6 +112,13 @@ def _flash_bytes(shape, p) -> int:
     return words * _WORD
 
 
+def _cholqr_defaults(shape):
+    """The kernel's own shape-aware tiles (lazy import: jax)."""
+    from repro.kernels.gram import gram_tall_tiles
+    bf, bd = gram_tall_tiles(*shape)
+    return {"block_f": bf, "block_d": bd}
+
+
 def _apply_track_defaults(shape):
     """The kernel's own shape-aware default tiles (lazy import: jax)."""
     from repro.kernels.fastmix import apply_track_default_tiles
@@ -132,7 +139,7 @@ def _rep_gram(m, d, k):
 
 
 def _rep_cholqr(m, d, k):
-    return (d, k)
+    return (m, d, k)          # one factor per agent
 
 
 def _rep_power_matmul(m, d, k):
@@ -157,8 +164,10 @@ KERNEL_MODELS: Dict[str, KernelModel] = {
     "gram": KernelModel(
         "gram", {"block_d": 128, "block_n": 512}, _gram_bytes, _rep_gram),
     "cholqr": KernelModel(
-        "cholqr", {"block_d": 128, "block_n": 512}, _cholqr_bytes,
-        _rep_cholqr),
+        # the tiles of the served (400, 300, 8) stack; other shapes take
+        # the kernel's own (defaults_for)
+        "cholqr", {"block_f": 100, "block_d": 300}, _cholqr_bytes,
+        _rep_cholqr, defaults_for=_cholqr_defaults),
     "power_matmul": KernelModel(
         "power_matmul", {"block_m": 512, "block_k": 512},
         _power_matmul_bytes, _rep_power_matmul),

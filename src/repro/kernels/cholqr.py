@@ -7,8 +7,8 @@ whose batched form loops a per-matrix custom call m times.  For tall-skinny
 factors the Gram-based route is the classical fix (LightLDA-style shifted
 CholeskyQR; Fukaya et al.'s CholeskyQR2):
 
-    G = X^T X          (k x k Gram — the same reduction the `gram` kernel
-                        tiles; k is in the tens)
+    G = X^T X          (k x k Gram — on TPU the Pallas `gram_tall`
+                        kernel, one call for the whole stack of factors)
     R = chol(G)^T      (upper-triangular k x k)
     Q = X R^{-1}       (one small-matrix multiply against the tall factor)
 
@@ -126,27 +126,16 @@ def _tri_inv_lower(L: jax.Array) -> jax.Array:
     return M
 
 
-def _gram_nk(X: jax.Array, *, use_kernel: bool, block_n: Optional[int],
-             interpret: bool) -> jax.Array:
+def _gram_nk(X: jax.Array, *, use_kernel: bool, interpret: bool) -> jax.Array:
     """``X^T X`` over the last two axes: ``(..., d, k) -> (..., k, k)``.
 
-    ``use_kernel`` routes through the Pallas ``gram`` kernel (TPU, or
-    interpret mode for the wiring tests) with its panel width resolved
-    from the autotune cache under the ``cholqr`` kernel name; otherwise a
-    HIGHEST-precision einsum — one fused batched matmul.
+    ``use_kernel`` routes through the Pallas ``gram_tall`` kernel (TPU, or
+    interpret mode for the wiring tests), which tiles itself from the
+    shape; otherwise a HIGHEST-precision einsum — one fused batched matmul.
     """
     if use_kernel:
-        from .gram import gram as _gram_kernel
-        d, k = X.shape[-2], X.shape[-1]
-        bn = block_n if block_n is not None else autotune.resolve(
-            "cholqr", "block_n", (d, k), X.dtype, default=512)
-        bd = autotune.resolve("cholqr", "block_d", (d, k), X.dtype,
-                              default=128)
-        fn = lambda x: _gram_kernel(x, block_d=bd, block_n=bn,
-                                    interpret=interpret)
-        for _ in range(X.ndim - 2):
-            fn = jax.vmap(fn)
-        return fn(X).astype(X.dtype)
+        from .gram import gram_tall
+        return gram_tall(X, interpret=interpret)
     return jnp.einsum("...dk,...dl->...kl", X, X,
                       precision=jax.lax.Precision.HIGHEST)
 
@@ -183,18 +172,16 @@ def _pivot_floor(G: jax.Array) -> jax.Array:
     return eps * jnp.trace(G, axis1=-2, axis2=-1) / k
 
 
-def _chol_pass(X: jax.Array, *, use_kernel: bool, block_n: Optional[int],
+def _chol_pass(X: jax.Array, *, use_kernel: bool,
                interpret: bool) -> jax.Array:
     """One plain (unscreened) CholeskyQR pass ``X -> Q``."""
-    G = _gram_nk(X, use_kernel=use_kernel, block_n=block_n,
-                 interpret=interpret)
+    G = _gram_nk(X, use_kernel=use_kernel, interpret=interpret)
     return _apply_rinv(X, _chol_small(G, pivot_floor=_pivot_floor(G)))
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("use_kernel", "block_n", "interpret"))
+                   static_argnames=("use_kernel", "interpret"))
 def cholqr2(X: jax.Array, *, use_kernel: Optional[bool] = None,
-            block_n: Optional[int] = None,
             interpret: Optional[bool] = None) -> jax.Array:
     """Batched CholeskyQR2: ``(..., d, k) -> (..., d, k)`` orthonormal Q.
 
@@ -216,7 +203,7 @@ def cholqr2(X: jax.Array, *, use_kernel: Optional[bool] = None,
     it = interpret is True
     dt = jnp.float64 if X.dtype == jnp.float64 else jnp.float32
     if dt == jnp.float64:
-        # the Pallas gram kernel accumulates in fp32; f64 factors must not
+        # the Pallas Gram kernel accumulates in fp32; f64 factors must not
         # round-trip through it ("f64 stays f64 end to end")
         use_kernel = False
     elif use_kernel is None:
@@ -225,7 +212,7 @@ def cholqr2(X: jax.Array, *, use_kernel: Optional[bool] = None,
     eps = float(jnp.finfo(dt).eps)
 
     # ---- pass 1, screened ------------------------------------------------
-    G1 = _gram_nk(x, use_kernel=use_kernel, block_n=block_n, interpret=it)
+    G1 = _gram_nk(x, use_kernel=use_kernel, interpret=it)
     L1 = _chol_small(G1, pivot_floor=_pivot_floor(G1))
     diag = jnp.diagonal(L1, axis1=-2, axis2=-1)
     trace = jnp.trace(G1, axis1=-2, axis2=-1)
@@ -244,11 +231,10 @@ def cholqr2(X: jax.Array, *, use_kernel: Optional[bool] = None,
     Q = _apply_rinv(x, L1)
 
     # ---- pass 2 (always) + conditional shifted-rescue pass 3 -------------
-    Q = _chol_pass(Q, use_kernel=use_kernel, block_n=block_n, interpret=it)
+    Q = _chol_pass(Q, use_kernel=use_kernel, interpret=it)
     Q = jax.lax.cond(
         jnp.any(bad),
-        lambda q: _chol_pass(q, use_kernel=use_kernel, block_n=block_n,
-                             interpret=it),
+        lambda q: _chol_pass(q, use_kernel=use_kernel, interpret=it),
         lambda q: q, Q)
     return Q
 

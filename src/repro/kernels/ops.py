@@ -68,12 +68,11 @@ def apply_track_fused(A: jax.Array, W: jax.Array, S: jax.Array,
                                  interpret=it, wire_bf16=wire_bf16)
 
 
-def cholqr2(X: jax.Array, *, block_n: Optional[int] = None,
-            interpret: Optional[bool] = None) -> jax.Array:
+def cholqr2(X: jax.Array, *, interpret: Optional[bool] = None) -> jax.Array:
     """Batched CholeskyQR2 orthonormalization (Eqn. 3.3 fast path)."""
     from . import cholqr as _cq
     it = _default_interpret() if interpret is None else interpret
-    return _cq.cholqr2(X, block_n=block_n, interpret=it)
+    return _cq.cholqr2(X, interpret=it)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,

@@ -32,6 +32,34 @@ def test_gram_property_random_shapes(n, d, seed):
                                rtol=1e-5, atol=1e-4)
 
 
+# -------------------------------------------------------------- gram_tall
+@pytest.mark.parametrize("lead,d,k,tiles", [
+    *[(lead, d, k, None)
+      for lead in [(), (50,), (8, 50)]
+      for d, k in [(300, 8), (300, 3), (40, 6), (7, 1), (1024, 16)]],
+    ((50,), 300, 8, (16, 300)),     # ragged last block of factors
+    ((3, 7), 40, 6, (5, 40)),
+    ((4,), 300, 8, (3, 128)),       # d panels, the last one zero padded
+], ids=str)
+def test_gram_tall_matches_einsum(lead, d, k, tiles):
+    from repro.kernels.gram import _gram_tall, gram_tall
+    rng = np.random.default_rng(d * 100 + k)
+    x = jnp.asarray(rng.standard_normal((*lead, d, k)), jnp.float32)
+    if tiles is None:
+        got = gram_tall(x, interpret=True)
+    else:
+        n = int(np.prod(lead))
+        got = _gram_tall(x.reshape(n, d, k), block_f=tiles[0],
+                         block_d=tiles[1], interpret=True)
+        got = got.reshape(*lead, k, k)
+    want = jnp.einsum("...dk,...dl->...kl", x, x,
+                      precision=jax.lax.Precision.HIGHEST)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    scale = float(jnp.max(jnp.abs(x))) ** 2 * d
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
+                               atol=1e-5 * scale)
+
+
 # ----------------------------------------------------------- power_matmul
 @pytest.mark.parametrize("d,k", [(16, 1), (64, 4), (200, 8), (256, 32),
                                  (300, 17)])

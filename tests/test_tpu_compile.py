@@ -15,6 +15,7 @@ must never happen while a module is imported or collected.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -116,3 +117,19 @@ def test_kernel_compiles_for_v5e(build, one_chip, monkeypatch):
     fn, args = build(lambda shape: _spec(shape, one_chip))
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("shape", [
+    (8, 50, 300, 8),      # w8a-m50.batch: 8 solves, k=5 padded to 8
+    (1, 50, 300, 8),      # w8a-m50.single
+    (64, 16, 512, 8),     # the fleet's 64 tenants
+], ids=str)
+def test_cholqr2_gram_compiles_unpadded(shape, one_chip, monkeypatch):
+    """CholeskyQR2's Gram is the Pallas kernel at the served shapes, and
+    no factor is padded to a (512, 128) panel on the way."""
+    monkeypatch.setattr(autotune, "_DEVICE_KIND", "tpu_v5_lite")
+    x = _spec(shape, one_chip)
+    text = jax.jit(lambda x: cholqr2(x, use_kernel=True)).lower(
+        x).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(r"\[(?:\d+,)*512,128\]", text)
