@@ -23,9 +23,9 @@ c. streaming and fleet at m=16, d=512, k=8: a ``StreamingDeEPCA``
    rows per agent, K=12, T=30), and two 64-tenant ``TrackerFleet`` legs
    with the serve CLI's mixed sample counts (56-74 rows per agent), both
    fed by the CLI's threaded ingest: ``serve_pca_fleet`` itself, as
-   ``serve --workload pca-fleet`` runs it (its accuracy is reported, not
-   gated: see ``FLEET_CLI_NOTE``), and a leg whose tenants start from
-   their agent 0's local basis, gated on accuracy.
+   ``serve --workload pca-fleet`` runs it (random starts), and a leg
+   whose tenants start from their agent 0's local basis, both gated on
+   accuracy.
 
 Four chips (``--chips 4``): ``DistributedDeEPCA`` with one agent per chip
 on a ring (``collective_permute``) and on a complete graph
@@ -84,12 +84,13 @@ SERVICE_TAN_MAX = 2e-4
 #: c: worst fleet tenant, last of 6 ticks of T_tick=3, tenants started
 #: from their agent 0's local basis (CPU rehearsal: 3.292e-2)
 FLEET_TAN_MAX = 0.4
-#: Why the serve CLI's fleet leg is reported and not gated.
-FLEET_CLI_NOTE = (
-    "known fault: Alg. 2's sign reference is the tenant's random W0; at "
-    "d=512 its columns are nearly orthogonal to the tracked subspace, "
-    "agents split on a column's sign, the tracked mean column cancels and "
-    "tan-theta jumps (ROADMAP)")
+#: c: worst tenant of the serve CLI's fleet, last of 6 ticks, tenants
+#: started from random bases at heterogeneity 1.0, one joining mid-run:
+#: the chip's reading (0.646, the joiner, seed 3000000109) times three,
+#: not the CPU's, since four tenants at these shapes there read
+#: 0.29-1.01 and 64 spread wider; with Alg. 2's reference pinned to W0
+#: this leg read 5.971 on the chip
+FLEET_CLI_TAN_MAX = 2.0
 #: the Pallas tracking+gossip kernel every fp32 phase must run
 GOSSIP_KERNEL = "_fastmix_track_kernel"
 #: four chips: distributed against stacked (4 virtual CPU devices:
@@ -394,8 +395,8 @@ def phase_fleet_cli(seed: int, *, tenants=64, m=16, d=512, k=8, ticks=6):
     """c. ``serve_pca_fleet`` as ``serve --workload pca-fleet`` runs it:
     the CLI's tenant mix (56-74 rows per agent, heterogeneity 1.0), random
     ``init_W0`` starts, threaded ingest and mid-run churn.  Programs, cold
-    launches and the kernel route are gated; accuracy is reported only
-    (:data:`FLEET_CLI_NOTE`)."""
+    launches, the kernel route and the worst tenant's accuracy are
+    gated."""
     from repro.launch import serve
 
     args = serve.build_parser().parse_args([
@@ -417,12 +418,9 @@ def phase_fleet_cli(seed: int, *, tenants=64, m=16, d=512, k=8, ticks=6):
     require_fleet("c.fleet_cli", fleet, out["steady_cold"])
     acc = fleet_accuracy(fleet, out["last"])
     worst = max(acc, key=acc.get)
-    report("c.fleet_cli",
-           tan_theta_worst_tenant_agent_vs_f64_last_tick=f"{acc[worst]:.3e}",
-           worst_tenant=worst,
-           tenants_over_fleet_limit=sum(v > FLEET_TAN_MAX
-                                        for v in acc.values()),
-           gated="no", note=repr(FLEET_CLI_NOTE))
+    report("c.fleet_cli", worst_tenant=worst)
+    check("c.fleet_cli", "tan_theta_worst_tenant_agent_vs_f64_last_tick",
+          acc[worst], FLEET_CLI_TAN_MAX)
 
 
 def phase_fleet(seed: int, *, tenants=64, m=16, d=512, k=8, n=64, K=6,

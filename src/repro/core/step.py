@@ -346,3 +346,19 @@ class PowerStep:
         r = self.rounds if rounds is None else rounds
         return lambda S, W, G_prev: dynamic.apply_mix_track_traced(
             S, W, G_prev, ops, L, eta, rounds=r)
+
+
+def mean_basis(W: jax.Array) -> jax.Array:
+    """Orthonormal basis of the agents' mean estimate ``mean_j W_j``, each
+    column's sign set by Alg. 2 against that mean (not left to the QR's
+    own convention).
+
+    ``W`` is ``(..., m, d, k)``; the result ``(..., d, k)``.  Trackers use
+    it as Alg. 2's sign reference of the next window: a drifting subspace
+    leaves any fixed start behind (a column's overlap with it crosses
+    zero, and agents then split on that column's sign), while the basis
+    the window resumes from stays within one window's drift of every
+    agent.
+    """
+    Wbar = jnp.mean(W, axis=-3)
+    return sign_adjust(qr_orth(Wbar), Wbar)

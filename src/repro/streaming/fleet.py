@@ -36,7 +36,12 @@ agree to floating-point rounding (the batched SVD/QR lowering differs
 from the solo trace's by vmap axis), and the fleet mirrors the solo
 tracker's decision arithmetic host-side (EWMA, floor, jump/restart
 thresholds, cold-start tick skip) so drift decisions coincide whenever
-thresholds are decisive; property-tested in ``tests/test_fleet.py``.  Two
+thresholds are decisive; property-tested in ``tests/test_fleet.py``.
+Alg. 2's sign reference is re-anchored at every window, as in the solo
+tracker: a tenant's first window uses its start ``W0``, every later one
+the sign-aligned mean basis of the carry it resumes from (a tick's base
+window: the previous tick's answer), so a subspace that drifts far from
+its start never splits the agents on a column's sign.  Two
 solo behaviors intentionally do NOT carry over: the live health-monitor
 escalation (a process-global signal that cannot be attributed to one
 tenant inside a batch) and dynamic topology schedules (the fleet is a
@@ -57,7 +62,7 @@ from repro.core import metrics
 from repro.core.algorithms import resolve_acceleration, resolve_engines
 from repro.core.driver import IterationDriver
 from repro.core.operators import StackedOperators
-from repro.core.step import Carry, PowerStep, qr_orth, rebase_carry
+from repro.core.step import Carry, PowerStep, mean_basis, rebase_carry
 from repro.core.topology import Topology
 from repro.runtime import telemetry, tracing
 from repro.runtime.config import get_config
@@ -125,6 +130,10 @@ class FleetTickReport(NamedTuple):
     warm_launches: int
     cold_launches: int
     latency_ms: float           # wall-clock over all buckets this tick
+    restarts: int               # tenants rebased this tick
+    escalations: int            # tenant escalation windows this tick
+    slot_windows: int           # slot-windows run: capacity per launch
+    useful_slot_windows: int    # of them, those a tenant keeps
 
 
 @dataclasses.dataclass
@@ -144,8 +153,8 @@ class _Bucket:
     key: tuple                  # (kind, m, d, n_pad, k, T_tick)
     capacity: int
     carry: Carry                # each slot-stacked: (C, m, d, k)
-    W0: jax.Array               # (C, d, k) per-slot init (sign reference)
-    Q_prev: jax.Array           # (C, d, k) previous-tick mean bases
+    Q_prev: jax.Array           # (C, d, k) previous-tick mean bases (a
+                                # joiner's W0): the tick's sign reference
     slots: List[Optional[str]]  # tenant id per slot, None = free
 
 
@@ -217,7 +226,8 @@ class TrackerFleet:
         self._signatures: set = set()
         self.stats = {"ticks": 0, "windows": 0, "warm_launches": 0,
                       "cold_launches": 0, "joins": 0, "leaves": 0,
-                      "restarts": 0, "escalations": 0, "slo_breaches": 0}
+                      "restarts": 0, "escalations": 0, "slo_breaches": 0,
+                      "slot_windows": 0, "useful_slot_windows": 0}
         self._rebase_cache: dict = {}
 
     # ---------------------------------------------------------- bucketing
@@ -263,8 +273,8 @@ class TrackerFleet:
         ``W0`` is the tenant's ``(d, k)`` orthonormal init; ``n`` its
         samples-per-agent (data operators).  The slot starts as a fresh
         tracker — ``W0`` broadcast into all three base carry slots, extras
-        zeroed — so the tenant's first tick is bit-identical to a new solo
-        tracker's.
+        zeroed, ``W0`` the first tick's sign reference — so the tenant's
+        first tick is bit-identical to a new solo tracker's.
         """
         if tid in self._tenants:
             raise ValueError(f"tenant {tid!r} already joined")
@@ -287,7 +297,6 @@ class TrackerFleet:
         W_b = jnp.broadcast_to(W0, (self.m,) + W0.shape).astype(dt)
         fresh = self.driver.step.normalize_carry((W_b, W_b, W_b))
         bkt.carry = scatter_carry(bkt.carry, slot, fresh)
-        bkt.W0 = bkt.W0.at[slot].set(W0.astype(bkt.W0.dtype))
         bkt.Q_prev = bkt.Q_prev.at[slot].set(W0.astype(bkt.Q_prev.dtype))
         self._tenants[tid] = _Tenant(tid=tid, bucket=key, slot=slot)
         self.stats["joins"] += 1
@@ -314,9 +323,9 @@ class TrackerFleet:
         dt = jnp.result_type(W0.dtype, jnp.float32)
         zero = jnp.zeros((C, m, d, k), dt)
         carry = tuple(zero for _ in range(self.driver.step.carry_slots))
-        W0s = jnp.broadcast_to(W0.astype(dt), (C, d, k))
-        return _Bucket(key=key, capacity=C, carry=carry, W0=W0s,
-                       Q_prev=W0s, slots=[None] * C)
+        Q = jnp.broadcast_to(W0.astype(dt), (C, d, k))
+        return _Bucket(key=key, capacity=C, carry=carry, Q_prev=Q,
+                       slots=[None] * C)
 
     def _grow_bucket(self, bkt: _Bucket) -> None:
         # a full pool doubles: one cold compile at the new batch shape
@@ -325,7 +334,6 @@ class TrackerFleet:
         C = bkt.capacity
         bkt.carry = tuple(jnp.concatenate([c, jnp.zeros_like(c)])
                           for c in bkt.carry)
-        bkt.W0 = jnp.concatenate([bkt.W0, bkt.W0])
         bkt.Q_prev = jnp.concatenate([bkt.Q_prev, bkt.Q_prev])
         bkt.slots.extend([None] * C)
         bkt.capacity = 2 * C
@@ -355,28 +363,38 @@ class TrackerFleet:
         host sync per window pass).
 
         Mirrors the solo tracker bit-for-bit: ``Q`` is
-        ``qr_orth(mean_j W_j)`` (the tracker's ``_mean_basis``), ``move``
+        ``mean_basis(W)`` (the tracker's ``_mean_basis``, the next tick's
+        sign reference), ``move``
         the ground-truth-free answer-movement statistic
         ``tan_theta_k(Q_prev, Q)``, ``mtt`` the paper's mean tan-theta
         against the supplied truth basis.
         """
-        Q = qr_orth(jnp.mean(W_b, axis=1))
+        Q = mean_basis(W_b)
         move = jax.vmap(metrics.tan_theta_k)(Q_prev_b, Q)
         mtt = jax.vmap(metrics.mean_tan_theta)(U_b, W_b)
         return Q, move, mtt
 
     def _window(self, bkt: _Bucket, ops_b: StackedOperators, carry: Carry,
-                T: Optional[int] = None
+                ref: jax.Array, T: Optional[int] = None, *,
+                kind: str = "base", useful: int = 0
                 ) -> Tuple[Carry, Optional[jax.Array]]:
+        """One batched window over every slot of the bucket, each slot's
+        Alg. 2 sign reference ``ref[slot]``; ``useful`` counts the slots
+        whose result a tenant keeps (``kind``: base, restart or
+        escalation)."""
         T = self.T_tick if T is None else int(T)
         sig = (bkt.key, bkt.capacity, T)
         warm = sig in self._signatures
         self._signatures.add(sig)
         self.stats["warm_launches" if warm else "cold_launches"] += 1
         self.stats["windows"] += 1
+        self.stats["slot_windows"] += bkt.capacity
+        self.stats["useful_slot_windows"] += useful
         self._tick_warm += int(warm)
         self._tick_cold += int(not warm)
-        out = self.driver.run_batch(ops_b, bkt.W0, T=T, carry=carry)
+        attrs = dict(kind=kind, T=T) if tracing.enabled() else {}
+        with tracing.span("fleet.window", **attrs):
+            out = self.driver.run_batch(ops_b, ref, T=T, carry=carry)
         return out.carries, out.diag
 
     # ---------------------------------------------------------------- tick
@@ -397,7 +415,7 @@ class TrackerFleet:
                 f"fleet tick must cover exactly the active tenants; "
                 f"missing={sorted(missing)} unknown={sorted(extra)}")
         self._tick_warm = self._tick_cold = 0
-        windows0 = self.stats["windows"]
+        before = dict(self.stats)
         reports: Dict[str, TenantReport] = {}
         tic_all = time.perf_counter()
         with tracing.span("fleet.tick", tick=self._ticks,
@@ -405,19 +423,25 @@ class TrackerFleet:
             for key, bkt in self._buckets.items():
                 active = [(s, tid) for s, tid in enumerate(bkt.slots)
                           if tid is not None]
-                if active:
+                if not active:
+                    continue
+                attrs = (dict(bucket=str(key), capacity=bkt.capacity,
+                              active=len(active))
+                         if tracing.enabled() else {})
+                with tracing.span("fleet.bucket", **attrs):
                     self._tick_bucket(bkt, active, items, reports)
         latency_ms = (time.perf_counter() - tic_all) * 1e3
+        delta = {k: self.stats[k] - before[k]
+                 for k in ("windows", "restarts", "escalations",
+                           "slot_windows", "useful_slot_windows")}
         report = FleetTickReport(
             tick=self._ticks, tenants=reports,
-            windows=self.stats["windows"] - windows0,
             warm_launches=self._tick_warm, cold_launches=self._tick_cold,
-            latency_ms=latency_ms)
+            latency_ms=latency_ms, **delta)
         telemetry.emit("fleet.tick", tick=self._ticks,
-                       tenants=len(reports), windows=report.windows,
-                       warm=report.warm_launches,
+                       tenants=len(reports), warm=report.warm_launches,
                        cold=report.cold_launches,
-                       latency_ms=latency_ms)
+                       latency_ms=latency_ms, **delta)
         self.stats["ticks"] += 1
         self._ticks += 1
         return report
@@ -432,31 +456,33 @@ class TrackerFleet:
         # tenant's zero-row-padded data; free slots ride a copy of the
         # first active tenant's (real, finite dynamics — the max-over-batch
         # diagnostics reduction must never see a QR of zeros)
-        payloads = {}
-        for s, tid in active:
-            item = items[tid]
-            if isinstance(item, StackedOperators):
-                ops, U = item, None
-            elif hasattr(item, "ops"):
-                ops, U = item.ops, getattr(item, "U", None)
-            else:
-                ops, U = item[0], (item[1] if len(item) > 1 else None)
-            payloads[s] = (self._pad_ops(ops, bkt.key), U)
-        filler_arr, _ = payloads[active[0][0]]
-        arrs = [payloads[s][0] if s in payloads else filler_arr
-                for s in range(bkt.capacity)]
-        arr = jnp.stack(arrs)
-        ops_b = (StackedOperators(dense=arr) if kind == "dense"
-                 else StackedOperators(data=arr))
-        U_b = jnp.stack([
-            (payloads[s][1] if s in payloads and payloads[s][1] is not None
-             else bkt.Q_prev[s])
-            for s in range(bkt.capacity)])
+        with tracing.span("fleet.stack"):
+            payloads = {}
+            for s, tid in active:
+                item = items[tid]
+                if isinstance(item, StackedOperators):
+                    ops, U = item, None
+                elif hasattr(item, "ops"):
+                    ops, U = item.ops, getattr(item, "U", None)
+                else:
+                    ops, U = item[0], (item[1] if len(item) > 1 else None)
+                payloads[s] = (self._pad_ops(ops, bkt.key), U)
+            filler_arr, _ = payloads[active[0][0]]
+            arrs = [payloads[s][0] if s in payloads else filler_arr
+                    for s in range(bkt.capacity)]
+            arr = jnp.stack(arrs)
+            ops_b = (StackedOperators(dense=arr) if kind == "dense"
+                     else StackedOperators(data=arr))
+            U_b = jnp.stack([
+                (payloads[s][1] if s in payloads
+                 and payloads[s][1] is not None else bkt.Q_prev[s])
+                for s in range(bkt.capacity)])
         has_U = {tid: payloads[s][1] is not None for s, tid in active}
 
         def stats(carry):
-            Q, move, mtt = self._stats_fn(carry[1], bkt.Q_prev, U_b)
-            return Q, np.asarray(move), np.asarray(mtt)
+            with tracing.span("fleet.stats"):
+                Q, move, mtt = self._stats_fn(carry[1], bkt.Q_prev, U_b)
+                return Q, np.asarray(move), np.asarray(mtt)
 
         def stat_of(tid, s, move_h, mtt_h):
             if has_U[tid]:
@@ -471,8 +497,12 @@ class TrackerFleet:
                 t.iters += T
                 t.rounds += T * K
 
-        # -- pass 1: the base window, every slot rides
-        carry, diag = self._window(bkt, ops_b, bkt.carry)
+        # -- pass 1: the base window, every slot rides.  Each window's
+        # Alg. 2 sign reference is the mean basis of the carry it resumes
+        # from: here the previous tick's (a joiner's W0), after it the
+        # ``Q`` of the last stats read
+        carry, diag = self._window(bkt, ops_b, bkt.carry, bkt.Q_prev,
+                                   useful=len(active))
         advance([tid for _, tid in active], self.T_tick)
         Q, move_h, mtt_h = stats(carry)
         jump = {tid: stat_of(tid, s, move_h, mtt_h) for s, tid in active}
@@ -497,7 +527,8 @@ class TrackerFleet:
                                 for tid in bkt.slots], bool)
             rebased = self._rebase_fn(kind)(arr, carry[1])
             rerun, _ = self._window(
-                bkt, ops_b, select_carry(mask, rebased, carry))
+                bkt, ops_b, select_carry(mask, rebased, carry), Q,
+                kind="restart", useful=sum(severe.values()))
             carry = select_carry(mask, rerun, carry)
             Q, move_h, mtt_h = stats(carry)
             hot = [tid for _, tid in active if severe[tid]]
@@ -529,7 +560,9 @@ class TrackerFleet:
                 break
             mask = jnp.asarray([go.get(tid, False)
                                 for tid in bkt.slots], bool)
-            rerun, _ = self._window(bkt, ops_b, carry, T=esc_T)
+            rerun, _ = self._window(bkt, ops_b, carry, Q, esc_T,
+                                    kind="escalation",
+                                    useful=sum(go.values()))
             carry = select_carry(mask, rerun, carry)
             Q, move_h, mtt_h = stats(carry)
             advance([tid for tid, g in go.items() if g], esc_T)

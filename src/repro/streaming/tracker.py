@@ -188,9 +188,9 @@ class StreamingDeEPCA:
         return (*self._carry, offset)
 
     # ------------------------------------------------------------ windows
-    def _window(self, ops: StackedOperators, W0: jax.Array, U, T: int):
+    def _window(self, ops: StackedOperators, U, T: int):
         """One resumed driver window + its resume-continuous trace."""
-        run = self.driver.run(ops, W0, T=T, t0=self._iters,
+        run = self.driver.run(ops, self._reference(), T=T, t0=self._iters,
                               carry=self._carry)
         trace = collect_trace(ops, U, run.S_hist, run.W_hist,
                               rounds=run.rounds, rounds0=int(self._rounds),
@@ -215,11 +215,20 @@ class StreamingDeEPCA:
         return float(metrics.tan_theta_k(self._Q_prev, self._mean_basis()))
 
     def _mean_basis(self) -> jax.Array:
-        """Orthonormal basis of the mean estimate — via the shared
-        ``qr_orth`` compute site, so streaming inherits the CholeskyQR2
-        fast path (PR 5) like every driver substrate."""
-        from repro.core.step import qr_orth
-        return qr_orth(jnp.mean(self._carry[1], axis=0))
+        """Orthonormal basis of the mean estimate, its column signs set
+        against that mean (:func:`~repro.core.step.mean_basis`, through
+        the shared ``qr_orth`` compute site)."""
+        from repro.core.step import mean_basis
+        return mean_basis(self._carry[1])
+
+    def _reference(self) -> jax.Array:
+        """Alg. 2's sign reference for the next window: ``W0`` for the
+        first, then the mean basis of the carry the window resumes from
+        (at a tick's start, the previous tick's answer).  A fixed ``W0``
+        stops working once the drift carries the subspace past 90 degrees
+        from it: a column's overlap with ``W0`` crosses zero, agents split
+        on its sign and the tracked mean column cancels."""
+        return self.W0 if self._carry is None else self._mean_basis()
 
     def _restart(self, ops: StackedOperators):
         """Rebase tracker state on the current operators.
@@ -263,7 +272,7 @@ class StreamingDeEPCA:
         rounds_before, iters_before = self._rounds, self._iters
         monitor = current_monitor()
         mark = monitor.mark() if monitor is not None else 0
-        traces = [self._window(ops, self.W0, U, self.T_tick)]
+        traces = [self._window(ops, U, self.T_tick)]
         stat = jump_stat = self._stat(traces[-1], U)
 
         # health escalation: when the live :class:`~repro.runtime
@@ -292,7 +301,7 @@ class StreamingDeEPCA:
             self._restart(ops)
             telemetry.emit("stream.restart", tick=self._ticks,
                            jump_stat=float(jump_stat))
-            traces.append(self._window(ops, self.W0, U, self.T_tick))
+            traces.append(self._window(ops, U, self.T_tick))
             stat = self._stat(traces[-1], U)
             restarted = True
 
@@ -302,7 +311,7 @@ class StreamingDeEPCA:
                     and stat > pol.target)
             if not (need or (drift and escalations == 0)):
                 break
-            traces.append(self._window(ops, self.W0, U, esc_T))
+            traces.append(self._window(ops, U, esc_T))
             stat = self._stat(traces[-1], U)
             escalations += 1
             telemetry.emit("stream.escalation", tick=self._ticks,

@@ -655,3 +655,75 @@ def test_run_batch_program_names_the_step_stages():
     text = svc.driver.lower_last_launch().as_text(debug_info=True)
     for scope in (SCOPE_APPLY, SCOPE_MIX, SCOPE_ORTH):
         assert scope in text, scope
+
+
+def _fleet_ticks(policy, ticks):
+    """Two tenants in one bucket of four slots over ``ticks`` ticks."""
+    from repro.core import erdos_renyi
+    from repro.streaming import SlowRotationStream, TrackerFleet
+    streams = {f"t{i}": SlowRotationStream(m=4, d=8, k=2, n_per_agent=10,
+                                           seed=i, rate=0.1)
+               for i in range(2)}
+    fleet = TrackerFleet(k=2, T_tick=2, K=2, slots=4, policy=policy,
+                         topology=erdos_renyi(4, p=0.6, seed=0),
+                         backend="stacked")
+    for tid, s in streams.items():
+        fleet.join(tid, s.init_W0(), n=10)
+    return fleet, [fleet.tick({tid: s.tick(t).ops
+                               for tid, s in streams.items()})
+                   for t in range(ticks)]
+
+
+def test_fleet_spans_and_counters_name_every_pass(tmp_path):
+    """Under ``fleet.tick`` each bucket's span holds the operator stacking,
+    one ``fleet.window`` per pass with its kind, and the ``fleet.stats``
+    read after each; the tick's report and event count the restarts,
+    escalations and the slot-windows run and kept."""
+    from repro.streaming import DriftPolicy
+    # the third tick restarts both tenants and escalates them once
+    policy = DriftPolicy(jump=1e-9, restart=1e-9, max_escalations=1)
+    tracing.set_tracer(tracing.ChromeTracer(str(tmp_path / "f.json")))
+    try:
+        with telemetry.capture() as rec:
+            fleet, reports = _fleet_ticks(policy, 3)
+    finally:
+        tracing.set_tracer(None)
+    bucket = str(fleet.bucket_of(8, 2, 10))
+
+    def window(kind, warm):
+        return ("fleet.window", {"kind": kind, "T": 2},
+                [("driver.stack", {}, []),
+                 ("driver.launch", {"substrate": "vmap", "T": 2,
+                                    "warm": warm}, [])])
+
+    def base(warm):
+        return [("fleet.stack", {}, []), window("base", warm),
+                ("fleet.stats", {}, [])]
+
+    restart = base(True) + [
+        window("restart", True), ("fleet.stats", {}, []),
+        window("escalation", True), ("fleet.stats", {}, [])]
+    ticks = [t for t in _span_tree(rec.of("span")) if t[0] == "fleet.tick"]
+    assert [t[1] for t in ticks] == [{"tick": t, "tenants": 2}
+                                     for t in range(3)]
+    buckets = [t[2] for t in ticks]
+    head = ("fleet.bucket", {"bucket": bucket, "capacity": 4, "active": 2})
+    assert buckets == [[head + (base(False),)], [head + (base(True),)],
+                       [head + (restart,)]]
+    keys = ("windows", "restarts", "escalations", "slot_windows",
+            "useful_slot_windows")
+    counts = [(1, 0, 0, 4, 2), (1, 0, 0, 4, 2), (3, 2, 2, 12, 6)]
+    assert [tuple(getattr(r, k) for k in keys) for r in reports] == counts
+    assert [tuple(e[k] for k in keys)
+            for e in rec.of("fleet.tick")] == counts
+
+
+def test_fleet_records_no_spans_without_tracer(monkeypatch):
+    from repro.streaming import DriftPolicy
+    opened = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        lambda *a, **k: opened.append(a))
+    with telemetry.capture() as rec:
+        _fleet_ticks(DriftPolicy(), 2)
+    assert rec.of("span") == [] and opened == []
+    assert [e["slot_windows"] for e in rec.of("fleet.tick")] == [4, 4]

@@ -19,6 +19,7 @@ from repro.core import (ConsensusEngine, IterationDriver, PowerStep,
                         TopologySchedule, deepca, depca, erdos_renyi,
                         metrics, rebase_carry, synthetic_spiked,
                         top_k_eigvecs)
+from repro.core.step import mean_basis
 from repro.streaming import (AdmissionPolicy, DriftPolicy,
                              EigengapShiftStream, PCAService,
                              SampleArrivalStream, SlowRotationStream,
@@ -56,8 +57,10 @@ def test_tick_bit_identical_to_resumed_call(algorithm):
     r1 = tr.tick(ops1, U1)
 
     a = fn(ops0, topo, W0, k=3, T=T, K=K, U=U0, backend="stacked")
-    b = fn(ops1, topo, W0, k=3, T=T, K=K, U=U1, backend="stacked",
-           state=a.state)
+    # the second tick's Alg. 2 sign reference is the first tick's mean
+    # basis (the tracker re-anchors it every tick)
+    b = fn(ops1, topo, mean_basis(a.state[1]), k=3, T=T, K=K, U=U1,
+           backend="stacked", state=a.state)
     # iterates and full resumable state
     np.testing.assert_array_equal(np.asarray(tr.W), np.asarray(b.W))
     for i in range(3):

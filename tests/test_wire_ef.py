@@ -233,6 +233,7 @@ def test_streaming_tick_bit_matches_resumed_accel_ef_run():
     accelerated momentum + an int8-EF wire are bit-identical to the
     equivalent resumed deepca calls, 5-slot carry included."""
     import math
+    from repro.core.step import mean_basis
     from repro.streaming import DriftPolicy, SlowRotationStream, \
         StreamingDeEPCA
     s = SlowRotationStream(m=6, d=16, k=3, n_per_agent=20, seed=0, rate=0.06)
@@ -250,7 +251,10 @@ def test_streaming_tick_bit_matches_resumed_accel_ef_run():
     kw = dict(k=3, T=4, K=4, backend="stacked", accelerated=True,
               wire_dtype="int8")
     a = deepca(ops0, topo, W0, U=U0, **kw)
-    b = deepca(ops1, topo, W0, U=U1, state=a.state, **kw)
+    # the second tick's Alg. 2 sign reference is the first tick's mean
+    # basis (the tracker re-anchors it every tick)
+    b = deepca(ops1, topo, mean_basis(a.state[1]), U=U1, state=a.state,
+               **kw)
     np.testing.assert_array_equal(np.asarray(tr.W), np.asarray(b.W))
     assert len(tr.state) == len(b.state) == 5 + 1
     for i in range(len(b.state)):
