@@ -258,8 +258,9 @@ def _build_driver_run(dtype):
     eng = ConsensusEngine(topology=_topology(), K=2, backend="stacked")
     driver = IterationDriver(step=PowerStep(track=True, rounds=2),
                              engine=eng)
-    fn, _warm = driver._scan_fn(2, "data")
     ops, W0 = _problem(dtype)
+    fn, _warm = driver._scan_fn(
+        2, "data", driver._apply_form("data", ops.array, 2))
     return fn, (ops.array, W0, _carry(ops, W0))
 
 

@@ -63,8 +63,8 @@ from repro.runtime import telemetry, tracing
 from repro.runtime import diagnostics as diagnostics_lib
 
 from .consensus import ConsensusEngine, DynamicConsensusEngine
-from .operators import StackedOperators
-from .step import Carry, PowerStep
+from .operators import StackedOperators, gram_form_pays
+from .step import SCOPE_APPLY, Carry, PowerStep
 
 SUBSTRATES = ("auto", "scan", "traced_scan", "unrolled")
 
@@ -347,29 +347,55 @@ class IterationDriver:
         return out
 
     @staticmethod
-    def _rebuild_ops(kind: str, arr: jax.Array) -> StackedOperators:
-        return (StackedOperators(dense=arr) if kind == "dense"
-                else StackedOperators(data=arr))
+    def _apply_form(kind: str, arr: jax.Array, T: int) -> Optional[str]:
+        """The apply's evaluation order for a launch of ``T`` iterations
+        over ``arr`` (``(..., n, d)`` rows): ``"gram"`` where
+        :func:`~repro.core.operators.gram_form_pays` holds, else
+        ``"data"``; ``None`` for dense operators.  Every single-device
+        launch (:meth:`run`'s substrates, :meth:`run_batch`, cold or
+        resumed) decides here, so one operator and ``T`` give one form."""
+        if kind == "dense":
+            return None
+        n, d = arr.shape[-2:]
+        return "gram" if gram_form_pays(n, d, T) else "data"
 
-    def _scan_fn(self, T: int, kind: str):
+    @staticmethod
+    def _rebuild_ops(kind: str, form: Optional[str],
+                     arr: jax.Array) -> StackedOperators:
+        """Operators from a launch's operand; the Gram form forms each
+        agent's Gram here, once per launch, never kept across launches."""
+        if kind == "dense":
+            return StackedOperators(dense=arr)
+        ops = StackedOperators(data=arr)
+        if form == "gram":
+            with jax.named_scope(SCOPE_APPLY):
+                ops = ops.with_gram()
+        return ops
+
+    @staticmethod
+    def _form_attrs(form: Optional[str]) -> dict:
+        return {} if form is None else {"form": form}
+
+    def _scan_fn(self, T: int, kind: str, form: Optional[str]):
         """Cached jitted static-topology scan over one problem.
 
         Returns ``(fn, warm)``.  The diagnostics spec is part of the cache
         key: diag-on and diag-off are distinct compiled programs, so
         toggling diagnostics never invalidates (or perturbs) the off path.
+        ``form`` (:meth:`_apply_form`) is part of it too.
         """
         spec = self.diagnostics
-        key = ("scan", T, kind, spec)
+        key = ("scan", T, kind, form, spec)
         fn = self._run_cache.get(key)
         warm = fn is not None
         telemetry.emit("launch", source="driver.run", substrate="scan",
-                       T=T, kind=kind, warm=warm)
+                       T=T, kind=kind, warm=warm, **self._form_attrs(form))
         if fn is None:
             step, eng = self.step, self.engine
             mix = step.make_mix(eng)
 
             def scan_fn(arr, W0, carry):
-                ops = self._rebuild_ops(kind, arr)
+                ops = self._rebuild_ops(kind, form, arr)
                 apply_mix = step.make_apply_mix(eng, ops)
 
                 def body(c, _):
@@ -384,20 +410,20 @@ class IterationDriver:
             fn = self._run_cache[key] = jax.jit(scan_fn)
         return fn, warm
 
-    def _traced_scan_fn(self, T: int, kind: str):
+    def _traced_scan_fn(self, T: int, kind: str, form: Optional[str]):
         """Cached jitted dynamic-schedule scan; ``(Ls, etas)`` are traced.
-        Returns ``(fn, warm)``; see :meth:`_scan_fn` on the diag key."""
+        Returns ``(fn, warm)``; see :meth:`_scan_fn` on the cache key."""
         spec = self.diagnostics
-        key = ("traced_scan", T, kind, spec)
+        key = ("traced_scan", T, kind, form, spec)
         fn = self._run_cache.get(key)
         warm = fn is not None
         telemetry.emit("launch", source="driver.run", substrate="traced_scan",
-                       T=T, kind=kind, warm=warm)
+                       T=T, kind=kind, warm=warm, **self._form_attrs(form))
         if fn is None:
             step, dyn = self.step, self.dynamic
 
             def scan_fn(arr, W0, carry, Ls, etas):
-                ops = self._rebuild_ops(kind, arr)
+                ops = self._rebuild_ops(kind, form, arr)
 
                 def body(c, xs):
                     L_t, eta_t = xs
@@ -418,9 +444,10 @@ class IterationDriver:
     def _run_scan(self, ops, W0, carry, T, t0, dt) -> DriverRun:
         K = self.step.rounds
         kind = "dense" if ops.dense is not None else "data"
-        fn, warm = self._scan_fn(T, kind)
+        form = self._apply_form(kind, ops.array, T)
+        fn, warm = self._scan_fn(T, kind, form)
         with tracing.span("driver.launch", substrate="scan", T=int(T),
-                          warm=warm):
+                          warm=warm, **self._form_attrs(form)):
             carry, ys = self._launch(fn, ops.array, W0, carry)
         S_hist, W_hist = ys[0], ys[1]
         diag = ys[2] if self.diagnostics is not None else None
@@ -432,9 +459,10 @@ class IterationDriver:
     def _run_traced_scan(self, ops, W0, carry, T, t0, dt) -> DriverRun:
         Ls, etas = self.dynamic.operands(t0, T, dtype=dt)
         kind = "dense" if ops.dense is not None else "data"
-        fn, warm = self._traced_scan_fn(T, kind)
+        form = self._apply_form(kind, ops.array, T)
+        fn, warm = self._traced_scan_fn(T, kind, form)
         with tracing.span("driver.launch", substrate="traced_scan", T=int(T),
-                          warm=warm):
+                          warm=warm, **self._form_attrs(form)):
             carry, ys = self._launch(fn, ops.array, W0, carry, Ls, etas)
         S_hist, W_hist = ys[0], ys[1]
         diag = ys[2] if self.diagnostics is not None else None
@@ -448,7 +476,11 @@ class IterationDriver:
         spec = self.diagnostics
         S_hist, W_hist, rounds, rates, diag = [], [], [], [], []
         total = 0
-        with tracing.span("driver.launch", substrate="unrolled", T=int(T)):
+        kind = "dense" if ops.dense is not None else "data"
+        form = self._apply_form(kind, ops.array, T)
+        with tracing.span("driver.launch", substrate="unrolled", T=int(T),
+                          **self._form_attrs(form)):
+            ops = self._rebuild_ops(kind, form, ops.array)
             for i in range(T):
                 t = t0 + i
                 r = step.rounds_at(t)
@@ -523,6 +555,12 @@ class IterationDriver:
         The gossip math runs in stacked/traced form (``shard_map`` cannot be
         vmapped over problems — devices are a physical axis); the tracking
         combine still routes through the shared compute site.
+
+        Data operators take the apply's evaluation order that :meth:`run`
+        takes at the same shapes and ``T`` (:meth:`_apply_form`): where
+        :func:`~repro.core.operators.gram_form_pays` holds, each agent's
+        Gram ``X_j^T X_j`` is formed once inside the launch at
+        ``HIGHEST`` precision and never kept across launches.
         """
         backend = (self.engine or self.dynamic).backend
         if backend == "shard_map":
@@ -551,6 +589,8 @@ class IterationDriver:
                     f"every slot; got shapes {bad}")
         resumed: Tuple[jax.Array, ...] = tuple(carry) if resume else ()
 
+        form = self._apply_form(kind, arr, T)
+        args: Tuple[jax.Array, ...] = (arr, W0)
         if self.dynamic is not None:
             offs = [0] * B if t0 is None else [int(x) for x in t0]
             if len(offs) != B:
@@ -560,19 +600,17 @@ class IterationDriver:
             for off in offs:
                 Ls_b, etas_b = self.dynamic.operands(off, T, dtype=dt)
                 ops_all.append((Ls_b, etas_b))
-            Ls = jnp.stack([o[0] for o in ops_all])
-            etas = jnp.stack([o[1] for o in ops_all])
-            fn, warm = self._batch_fn(T, kind, with_history, dynamic=True,
-                                      resume=resume)
-            with tracing.span("driver.launch", substrate="vmap", T=int(T),
-                              warm=warm):
-                out = self._launch(fn, arr, W0, Ls, etas, *resumed)
-        else:
-            fn, warm = self._batch_fn(T, kind, with_history, dynamic=False,
-                                      resume=resume)
-            with tracing.span("driver.launch", substrate="vmap", T=int(T),
-                              warm=warm):
-                out = self._launch(fn, arr, W0, *resumed)
+            args += (jnp.stack([o[0] for o in ops_all]),
+                     jnp.stack([o[1] for o in ops_all]))
+        fn, warm = self._batch_fn(T, kind, form, with_history,
+                                  dynamic=self.dynamic is not None,
+                                  resume=resume)
+        attrs = {}
+        if tracing.enabled():
+            attrs = dict(substrate="vmap", T=int(T), warm=warm,
+                         **self._form_attrs(form))
+        with tracing.span("driver.launch", **attrs):
+            out = self._launch(fn, *args, *resumed)
         carry, hists, dvals = out
         diag = dvals if self.diagnostics is not None else None
         S, W, G_prev = carry[:3]
@@ -619,14 +657,17 @@ class IterationDriver:
         kind = kinds.pop()
         return kind, jnp.stack([o.array for o in ops_batch])
 
-    def _batch_fn(self, T: int, kind: str, with_history: bool,
-                  dynamic: bool, resume: bool = False):
+    def _batch_fn(self, T: int, kind: str, form: Optional[str],
+                  with_history: bool, dynamic: bool, resume: bool = False):
+        """Cached jitted vmapped program; ``form`` (``"gram"``/``"data"``
+        for data operators, ``None`` for dense) picks the apply's
+        evaluation order.  Returns ``(fn, warm)``."""
         spec = self.diagnostics
-        key = (T, kind, with_history, dynamic, resume, spec)
+        key = (T, kind, form, with_history, dynamic, resume, spec)
         fn = self._batch_cache.get(key)
         warm = fn is not None
         telemetry.emit("launch", source="driver.run_batch", substrate="vmap",
-                       T=T, kind=kind, warm=warm)
+                       T=T, kind=kind, warm=warm, **self._form_attrs(form))
         if fn is not None:
             return fn, warm
         step, eng, dyn = self.step, self.engine, self.dynamic
@@ -636,8 +677,7 @@ class IterationDriver:
             return carry, hists, (ys[2] if spec is not None else ())
 
         def one_static(arr, W0_b, *carry_in):
-            ops_b = (StackedOperators(dense=arr) if kind == "dense"
-                     else StackedOperators(data=arr))
+            ops_b = self._rebuild_ops(kind, form, arr)
             carry = carry_in if resume else step.init_carry(ops_b, W0_b)
             mix = step.make_mix(eng)
             apply_mix = step.make_apply_mix(eng, ops_b)
@@ -653,8 +693,7 @@ class IterationDriver:
             return split_ys(carry, ys)
 
         def one_dynamic(arr, W0_b, Ls_b, etas_b, *carry_in):
-            ops_b = (StackedOperators(dense=arr) if kind == "dense"
-                     else StackedOperators(data=arr))
+            ops_b = self._rebuild_ops(kind, form, arr)
             carry = carry_in if resume else step.init_carry(ops_b, W0_b)
 
             def body(c, xs):

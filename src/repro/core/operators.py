@@ -5,7 +5,10 @@ At LM scale materializing ``A_j`` is an O(d^2) memory blow-up, so we also
 support the implicit Gram form ``A_j = X_j^T X_j`` (data ``X_j in R^{n x d}``)
 where the power step is fused as ``X_j^T (X_j W)`` — two tall-skinny matmuls,
 never forming d x d.  Section 5 of the paper (Eqn. 5.1) is exactly this Gram
-construction.
+construction.  Where a launch runs enough iterations over tall enough rows,
+forming the Gram once costs less than reading the rows twice an iteration
+(:func:`gram_form_pays`); a data operator then carries its formed Gram
+beside its rows (:meth:`StackedOperators.with_gram`).
 """
 from __future__ import annotations
 
@@ -17,19 +20,65 @@ import jax.numpy as jnp
 import numpy as np
 
 
+# multiply-adds at HIGHEST (six bf16 MXU passes) a v5e does in the time it
+# reads one float32 word: 197 TFLOP/s / 2 / 6 against 819 GB/s / 4
+MACS_PER_WORD = 80
+
+
+def gram_form_pays(n: int, d: int, T: int) -> bool:
+    """True when a launch of ``T`` applies of ``A_j = X_j^T X_j`` costs
+    less with the Gram formed than in data form, for ``(n, d)`` rows an
+    agent.
+
+    Both applies are bound by bytes, so the cost is the words read, with
+    the formation's ``n*d*d`` multiply-adds counted at
+    :data:`MACS_PER_WORD` a word: at ``n ~ d ~ 8192, T = 30`` forming costs
+    more than the data form's whole launch.  The Grams must also be no larger
+    than the rows (``d <= n``): the launch then holds at most twice its
+    operands, and the Gram apply never does more multiply-adds than the
+    data form's, whatever ``k``.  Shapes and ``T`` decide; there is no
+    knob, and the device's free memory is not consulted.
+    """
+    # words a launch: data 2*T*n*d; Gram T*d*d + n*d + n*d*d/MACS_PER_WORD
+    return d <= n and (T * d * d + n * d + n * d * d / MACS_PER_WORD
+                       < 2 * T * n * d)
+
+
+def data_gram(X: jax.Array) -> jax.Array:
+    """``(m, n, d)`` rows -> ``(m, d, d)`` Grams ``X_j^T X_j`` (Eqn. 5.1)."""
+    return jnp.einsum("mnd,mne->mde", X, X,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
 @dataclasses.dataclass(frozen=True)
 class StackedOperators:
     """Agent-stacked local operators.
 
-    Exactly one of ``dense`` (m, d, d) or ``data`` (m, n, d) is set.
+    Exactly one of ``dense`` (m, d, d) or ``data`` (m, n, d) is set.  A data
+    operator may also carry ``gram`` (m, d, d), its rows' formed Grams
+    (:meth:`with_gram`): :meth:`apply` then reads the Grams, not the rows.
     """
 
     dense: Optional[jax.Array] = None   # (m, d, d)
     data: Optional[jax.Array] = None    # (m, n, d) -> A_j = X_j^T X_j
+    # (m, d, d) = data_gram(data), formed inside a launch by the driver.
+    # Kept apart from ``dense`` only so the engine keeps the apply an XLA
+    # product (not ``apply_track_fused``, which no benchmark reader
+    # measures yet); to be folded into ``dense`` once one does.
+    gram: Optional[jax.Array] = None
 
     def __post_init__(self):
         if (self.dense is None) == (self.data is None):
             raise ValueError("exactly one of dense/data must be given")
+        if self.gram is not None and self.data is None:
+            raise ValueError("gram is carried by data operators only")
+
+    def with_gram(self) -> "StackedOperators":
+        """This data operator with its Grams formed, so each :meth:`apply`
+        reads ``d*d`` words an agent in place of ``2*n*d``."""
+        if self.data is None:
+            raise ValueError("with_gram needs a data operator")
+        return dataclasses.replace(self, gram=data_gram(self.data))
 
     @property
     def array(self) -> jax.Array:
@@ -51,8 +100,9 @@ class StackedOperators:
 
     def apply(self, W: jax.Array) -> jax.Array:
         """Stacked power step: returns (m, d, k) with slice_j = A_j W_j."""
-        if self.dense is not None:
-            return jnp.einsum("mde,mek->mdk", self.dense, W,
+        A = self.dense if self.dense is not None else self.gram
+        if A is not None:
+            return jnp.einsum("mde,mek->mdk", A, W,
                               precision=jax.lax.Precision.HIGHEST)
         XW = jnp.einsum("mnd,mdk->mnk", self.data, W,
                         precision=jax.lax.Precision.HIGHEST)
@@ -63,9 +113,7 @@ class StackedOperators:
         """A = (1/m) sum_j A_j, materialized (reference / ground truth only)."""
         if self.dense is not None:
             return jnp.mean(self.dense, axis=0)
-        gram = jnp.einsum("mnd,mne->mde", self.data, self.data,
-                          precision=jax.lax.Precision.HIGHEST)
-        return jnp.mean(gram, axis=0)
+        return jnp.mean(data_gram(self.data), axis=0)
 
     def spectral_bound(self) -> float:
         """L with ||A_j||_2 <= L for all j (paper's Lemma 1 constant)."""

@@ -21,6 +21,7 @@ from repro.core import (ConsensusEngine, DynamicConsensusEngine,
                         IterationDriver, PowerStep, TopologySchedule, deepca,
                         depca, erdos_renyi, sign_adjust, synthetic_spiked,
                         top_k_eigvecs)
+from repro.core.operators import gram_form_pays
 
 jax.config.update("jax_enable_x64", False)
 
@@ -45,6 +46,14 @@ def _qr(S):
     return qr_orth(S)
 
 
+def _apply(ops, T):
+    # The apply is such a site too: a launch of T iterations evaluates
+    # A_j W_j in the order gram_form_pays picks for its shapes, so the
+    # legacy bodies apply the operator in that same form.
+    n, d = ops.data.shape[1:]
+    return (ops.with_gram() if gram_form_pays(n, d, T) else ops).apply
+
+
 # ------------------------------------------------- substrate 1: static scan
 def test_driver_matches_legacy_static_scan():
     ops, U, W0 = _setup()
@@ -56,10 +65,11 @@ def test_driver_matches_legacy_static_scan():
     # legacy deepca scan body, verbatim
     W = jnp.broadcast_to(W0, (8,) + W0.shape).astype(jnp.float32)
     mix = eng.mix
+    apply = _apply(ops, T)
 
     def legacy_step(carry, _):
         S, W, G_prev = carry
-        G = ops.apply(W)
+        G = apply(W)
         S_new = S + G - G_prev
         S_new = mix(S_new)
         W_new = sign_adjust(_qr(S_new), W0)
@@ -75,7 +85,7 @@ def test_driver_matches_legacy_static_scan():
 
     # legacy depca scan body, verbatim
     def legacy_depca_step(W_stack, _):
-        G = ops.apply(W_stack)
+        G = apply(W_stack)
         G = eng.mix(G, rounds=K)
         W_new = sign_adjust(_qr(G), W0)
         return W_new, (G, W_new)
@@ -95,11 +105,12 @@ def test_driver_matches_legacy_traced_scan():
                                                backend="stacked")
     Ls, etas = dyn.operands(0, T, dtype=jnp.float32)
     W = jnp.broadcast_to(W0, (8,) + W0.shape).astype(jnp.float32)
+    apply = _apply(ops, T)
 
     def legacy_step(carry, xs):
         L_t, eta_t = xs
         S, W, G_prev = carry
-        G = ops.apply(W)
+        G = apply(W)
         S_new = S + G - G_prev
         S_new = dyn.mix_traced(S_new, L_t, eta_t)
         W_new = sign_adjust(_qr(S_new), W0)
@@ -122,8 +133,9 @@ def test_driver_matches_legacy_unrolled():
 
     # legacy increasing-consensus loop, verbatim
     W_stack = jnp.broadcast_to(W0, (8,) + W0.shape).astype(jnp.float32)
+    apply = _apply(ops, T)
     for t in range(T):
-        G = ops.apply(W_stack)
+        G = apply(W_stack)
         G = eng.mix(G, rounds=K + t)
         W_stack = sign_adjust(_qr(G), W0)
     res = depca(ops, topo, W0, k=2, T=T, K=K, U=U, backend="stacked",
@@ -138,8 +150,9 @@ def test_driver_matches_legacy_unrolled():
     dyn = DynamicConsensusEngine.for_algorithm("depca", sched, K=K,
                                                backend="stacked")
     W_stack = jnp.broadcast_to(W0, (8,) + W0.shape).astype(jnp.float32)
+    apply = _apply(ops, T)
     for t in range(T):
-        G = ops.apply(W_stack)
+        G = apply(W_stack)
         topo_t = dyn.topology_at(t)
         G = dyn.mix_traced(G, jnp.asarray(topo_t.mixing, jnp.float32),
                            dyn.eta_of(topo_t), rounds=K)

@@ -616,7 +616,8 @@ def test_service_spans_nest_launch_work_under_submit_and_poll(tmp_path):
                 work + [("service.stack", {}, []),
                         ("driver.stack", {}, []),
                         ("driver.launch",
-                         {"substrate": "vmap", "T": 2, "warm": lid > 0},
+                         {"substrate": "vmap", "T": 2, "warm": lid > 0,
+                          "form": "gram"},
                          [])])
 
     assert _span_tree(rec.of("span")) == [
@@ -643,6 +644,46 @@ def test_service_records_no_spans_without_tracer(monkeypatch):
     assert rec.of("span") == [] and opened == []
     # the launch events still carry their attributes for a live sink
     assert [e["batch"] for e in rec.of("service.launch")] == [2, 1]
+
+
+def test_run_batch_launch_names_the_apply_form(tmp_path):
+    """``driver.launch`` and the ``launch`` event say which evaluation
+    order the apply took: ``gram`` for a data batch whose rows are tall
+    against the launch's iterations, ``data`` for a fleet-like one, and
+    nothing for a dense batch."""
+    from repro.core import (ConsensusEngine, IterationDriver, PowerStep,
+                            erdos_renyi)
+    from repro.core.operators import StackedOperators, data_gram
+    m, k = 6, 3
+    driver = IterationDriver(
+        step=PowerStep.for_algorithm("deepca", 2),
+        engine=ConsensusEngine.for_algorithm(
+            "deepca", erdos_renyi(m, p=0.6, seed=0), K=2, backend="stacked"))
+    rng = np.random.default_rng(0)
+
+    def batch(n, d):
+        X = jnp.asarray(rng.standard_normal((2, m, n, d)), jnp.float32)
+        W0 = jnp.stack([jnp.eye(d, k)] * 2)
+        return X, W0
+
+    w8a_like, fleet_like = batch(64, 16), batch(8, 64)
+    tracer = tracing.ChromeTracer(str(tmp_path / "f.json"))
+    tracing.set_tracer(tracer)
+    try:
+        with telemetry.capture() as rec:
+            driver.run_batch(StackedOperators(data=w8a_like[0]),
+                             w8a_like[1], T=12)
+            driver.run_batch(StackedOperators(data=fleet_like[0]),
+                             fleet_like[1], T=3)
+            driver.run_batch(StackedOperators(
+                dense=jax.vmap(data_gram)(w8a_like[0])), w8a_like[1], T=12)
+    finally:
+        tracing.set_tracer(None)
+    launches = [e["args"] for e in json.loads(open(tracer.save()).read())[
+        "traceEvents"] if e["name"] == "driver.launch"]
+    assert [a.get("form") for a in launches] == ["gram", "data", None]
+    assert [(e["kind"], e.get("form")) for e in rec.of("launch")] == [
+        ("data", "gram"), ("data", "data"), ("dense", None)]
 
 
 def test_run_batch_program_names_the_step_stages():
@@ -694,7 +735,7 @@ def test_fleet_spans_and_counters_name_every_pass(tmp_path):
         return ("fleet.window", {"kind": kind, "T": 2},
                 [("driver.stack", {}, []),
                  ("driver.launch", {"substrate": "vmap", "T": 2,
-                                    "warm": warm}, [])])
+                                    "warm": warm, "form": "gram"}, [])])
 
     def base(warm):
         return [("fleet.stack", {}, []), window("base", warm),

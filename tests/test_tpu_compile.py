@@ -133,3 +133,36 @@ def test_cholqr2_gram_compiles_unpadded(shape, one_chip, monkeypatch):
         x).compile().as_text()
     assert "tpu_custom_call" in text
     assert not re.search(r"\[(?:\d+,)*512,128\]", text)
+
+
+def test_w8a_batch_applies_the_gram_on_v5e(one_chip, monkeypatch):
+    """The served w8a batch (8 problems, 1008 padded rows of d=300, k=5
+    padded to 8) compiles for the chip with the Gram formed before the
+    scan: the loop carries each agent's (300, 300) Gram and not its rows,
+    so no dot in the scan body reads a (1008, 300) operand, and tracking
+    and gossip stay in the FastMix kernel (not the dense apply kernel)."""
+    from repro.core import (ConsensusEngine, IterationDriver, PowerStep,
+                            erdos_renyi)
+    from repro.core.operators import gram_form_pays
+    monkeypatch.setattr(autotune, "_DEVICE_KIND", "tpu_v5_lite")
+    # pick the chip's routes (kernels, not the off-TPU fallbacks)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    B, m, n, d, k, T = 8, 50, 1008, 300, 8, 40
+    assert gram_form_pays(n, d, T)
+    driver = IterationDriver(
+        step=PowerStep.for_algorithm("deepca", 8),
+        engine=ConsensusEngine.for_algorithm(
+            "deepca", erdos_renyi(m, p=0.5, seed=0), K=8, backend="pallas"))
+    fn, _ = driver._batch_fn(T, "data", "gram", False, dynamic=False)
+    lowered = fn.lower(_spec((B, m, n, d), one_chip),
+                       _spec((B, d, k), one_chip))
+    names = set(re.findall(r'kernel_name = "(\w+)"', lowered.as_text()))
+    assert "_fastmix_track_kernel" in names
+    assert not any("apply_track" in name for name in names)
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+    loops = [line for line in text.splitlines() if " while(" in line]
+    assert loops
+    for line in loops:
+        assert f"{m},{d},{d}]" in line
+        assert f"{n},{d}]" not in line
