@@ -437,7 +437,8 @@ def sweep_degraded(writer=None, m: int = 16, K: int = 8, steps: int = 6,
                     gaps.append(tp.spectral_gap)
                     bounds.append(tp.fastmix_rate(K))
                     contractions.append(
-                        float(consensus_error(eng.mix_at(S, t))) / e0)
+                        float(consensus_error(
+                            eng.engine_at(t).mix(S))) / e0)
                 row = (topo.name, nd, p,
                        (float(np.min(gaps)), float(np.mean(contractions)),
                         float(np.mean(bounds)), base.m))

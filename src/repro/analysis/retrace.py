@@ -112,7 +112,9 @@ def _build_dynamic_swap():
     ops, W0 = _mini_problem(m=m)
     S = jnp.broadcast_to(W0, (m,) + W0.shape)
     G = ops.apply(S)
-    fn = jax.jit(dyn.mix_track_traced)
+    eng = dyn.engine_at(0)
+    fn = jax.jit(lambda S, G, Gp, L, eta: eng.mix_track(S, G, Gp,
+                                                        mixing=(L, eta)))
     L_ring = jnp.asarray(ring(m).mixing, jnp.float32)
     L_er = jnp.asarray(erdos_renyi(m, p=0.6, seed=3).mixing, jnp.float32)
 

@@ -259,9 +259,10 @@ def _build_driver_run(dtype):
     driver = IterationDriver(step=PowerStep(track=True, rounds=2),
                              engine=eng)
     ops, W0 = _problem(dtype)
-    fn, _warm = driver._scan_fn(
+    fn, _warm = driver.program(
         2, "data", driver._apply_form("data", ops.array, 2))
-    return fn, (ops.array, W0, _carry(ops, W0))
+    return (lambda arr, w0, *carry: fn(arr, w0, None, *carry)), \
+        (ops.array, W0) + tuple(_carry(ops, W0))
 
 
 def _engine(dtype, backend, wire=None, interpret=None):
@@ -314,8 +315,9 @@ def _build_dynamic_mix_track(backend, wire=None, interpret=None):
         S, W, Gp = _carry(ops, W0)
         G = ops.apply(W)
         Ls, etas = dyn.operands(0, 1, dtype=S.dtype)
+        eng = dyn.engine_at(0)
         return (lambda s, g, gp, L, eta:
-                dyn.mix_track_traced(s, g, gp, L, eta)), \
+                eng.mix_track(s, g, gp, mixing=(L, eta))), \
             (S, G, Gp, Ls[0], etas[0])
     return build
 
@@ -365,8 +367,9 @@ def _build_dynamic_mix_track_ef(backend, wire, interpret=None):
         S, W, Gp = _carry(ops, W0)
         G = ops.apply(W)
         Ls, etas = dyn.operands(0, 1, dtype=S.dtype)
+        eng = dyn.engine_at(0)
         return (lambda s, g, gp, L, eta, e:
-                dyn.mix_track_traced(s, g, gp, L, eta, ef=e)[0]), \
+                eng.mix_track(s, g, gp, ef=e, mixing=(L, eta))[0]), \
             (S, G, Gp, Ls[0], etas[0], jnp.zeros_like(S))
     return build
 
@@ -404,7 +407,7 @@ TRACE_SPECS = (
     TraceSpec("engine.apply_mix_track[pallas]",
               _build_engine_apply_mix_track("pallas", interpret=True),
               ("f64",)),
-    TraceSpec("dynamic.mix_track_traced[pallas]",
+    TraceSpec("engine.mix_track[pallas,traced]",
               _build_dynamic_mix_track("pallas", interpret=True), ("f64",)),
     # wire-precision paths: every bf16 configuration the engines expose
     TraceSpec("engine.mix[stacked,wire]",
@@ -420,7 +423,7 @@ TRACE_SPECS = (
     TraceSpec("engine.apply_mix_track[pallas,wire]",
               _build_engine_apply_mix_track("pallas", wire="bf16",
                                             interpret=True), ("wire",)),
-    TraceSpec("dynamic.mix_track_traced[pallas,wire]",
+    TraceSpec("engine.mix_track[pallas,traced,wire]",
               _build_dynamic_mix_track("pallas", wire="bf16",
                                        interpret=True), ("wire",)),
     TraceSpec("mixing.fastmix_wire", _build_fastmix_wire, ("wire",)),
@@ -438,7 +441,7 @@ TRACE_SPECS = (
     TraceSpec("engine.mix_track[pallas,fp8]",
               _build_engine_mix_track_ef("pallas", "fp8", interpret=True),
               ("wire:fp8",)),
-    TraceSpec("dynamic.mix_track_traced[pallas,fp8]",
+    TraceSpec("engine.mix_track[pallas,traced,fp8]",
               _build_dynamic_mix_track_ef("pallas", "fp8", interpret=True),
               ("wire:fp8",)),
     TraceSpec("mixing.fastmix_wire_ef[int8]", _build_fastmix_wire_ef("int8"),

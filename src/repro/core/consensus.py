@@ -12,10 +12,9 @@ and :class:`repro.core.gossip_shard.DistributedDeEPCA` wrap) and
 :func:`repro.launch.steps.make_train_step_compressed` consume, and is
 the seam later scaling work (async gossip, time-varying topologies,
 multi-mesh) plugs into.  The ``mix_track`` family
-(:meth:`ConsensusEngine.mix_track`, :meth:`ConsensusEngine.local_mix_track`,
-:meth:`DynamicConsensusEngine.mix_track_traced`) additionally fuses the
-DeEPCA subspace-tracking combine into the gossip call — on the ``pallas``
-backend inside the kernel launch itself.
+(:meth:`ConsensusEngine.mix_track`, :meth:`ConsensusEngine.local_mix_track`)
+additionally fuses the DeEPCA subspace-tracking combine into the gossip
+call — on the ``pallas`` backend inside the kernel launch itself.
 
 Backends
 --------
@@ -61,10 +60,11 @@ round, not fixed.  :class:`DynamicConsensusEngine` runs gossip over a
 retracing the hot path:
 
 * ``stacked`` / ``pallas`` consume the mixing matrix ``L`` and momentum
-  ``eta`` as **traced operands** (:meth:`DynamicConsensusEngine.mix_traced`)
-  — the jit cache is keyed on shape, so any same-``m`` graph swap reuses the
-  compiled computation.  ``deepca(schedule=...)`` stacks the per-step
-  ``(T, m, m)`` matrices and scans over them.
+  ``eta`` as **traced operands** (the ``mixing=(L, eta)`` argument of
+  :meth:`ConsensusEngine.mix` and its siblings) — the jit cache is keyed
+  on shape, so any same-``m`` graph swap reuses the compiled computation.
+  ``deepca(schedule=...)`` stacks the per-step ``(T, m, m)`` matrices and
+  scans over them.
 * ``shard_map`` keeps the ``collective_permute`` lowering only while the
   mixing matrix *structurally* matches a ring/hypercube
   (:func:`repro.core.gossip_shard.ring_structure` /
@@ -176,11 +176,16 @@ def _check_ef(wire_dtype: Optional[str], ef) -> bool:
     return False
 
 
+def _fused_dtype(S: jax.Array):
+    """The fused paths accumulate in fp32; f64 iterates stay f64."""
+    return jnp.float64 if S.dtype == jnp.float64 else jnp.float32
+
+
 def _fused_track_mix(S: jax.Array, G: jax.Array, G_prev: jax.Array,
                      L: jax.Array, eta, rounds: int, *,
                      interpret: Optional[bool], block_n: Optional[int],
                      wire: bool = False) -> jax.Array:
-    """Fused tracking+gossip dispatch (pallas backend, static and dynamic).
+    """Fused tracking+gossip dispatch (pallas backend, any ``(L, eta)``).
 
     Same dtype/precision contract as :func:`_fused_mix`; the subspace-
     tracking combine rides inside the fused launch so the tracked iterate
@@ -211,7 +216,7 @@ def _fused_track_mix(S: jax.Array, G: jax.Array, G_prev: jax.Array,
 def _fused_mix(S: jax.Array, L: jax.Array, eta, rounds: int, *,
                interpret: Optional[bool], block_n: Optional[int],
                wire: bool = False) -> jax.Array:
-    """Fused-backend dispatch shared by the static and dynamic engines.
+    """Fused-backend dispatch for the engine's own or a traced ``(L, eta)``.
 
     fp32 accumulation in both fused paths; cast back so the engine
     preserves the caller's dtype like the stacked reference does.
@@ -346,9 +351,9 @@ class ConsensusEngine:
     interpret: Optional[bool] = None
     block_n: Optional[int] = None
     wire_dtype: Optional[str] = None
-    # per-rounds cache of jitted shard_map mix fns (jax's dispatch cache is
-    # keyed on function identity, so rebuilding the closure per call would
-    # re-trace every time)
+    # per-(rounds, traced) cache of jitted shard_map mix fns (jax's dispatch
+    # cache is keyed on function identity, so rebuilding the closure per
+    # call would re-trace every time)
     _sharded_mix_cache: dict = dataclasses.field(
         default_factory=dict, init=False, repr=False, compare=False)
     # per-dtype cache of the device-resident mixing matrix, so eager hot
@@ -427,73 +432,92 @@ class ConsensusEngine:
         return WIRE_QUANT_FLOOR[self.wire_dtype]
 
     # ------------------------------------------------- stacked-form mixing
+    def _mixing(self, mixing, dtype):
+        """``(L, eta)`` in ``dtype``: the engine's own constants, or the
+        traced ``mixing`` pair a schedule scans over (any same-``m`` graph
+        then reuses the compiled program)."""
+        if mixing is None:
+            return self._L(dtype), self.eta
+        L, eta = mixing
+        return L.astype(dtype), eta
+
+    def _check_agents(self, S: jax.Array, mixing):
+        m = self.topology.m if mixing is None else mixing[0].shape[-1]
+        if S.shape[0] != m:
+            raise ValueError(
+                f"leading (agent) axis {S.shape[0]} != topology m={m}")
+
     def mix(self, S: jax.Array, rounds: Optional[int] = None, *,
-            ef: Optional[jax.Array] = None):
+            ef: Optional[jax.Array] = None, mixing=None):
         """Mix stacked ``(m, ...)`` agent variables; preserves the mean.
 
         ``rounds`` overrides the engine default K (static per call — this
-        is what DePCA's increasing-consensus schedule uses).  On EF wire
-        modes (:data:`EF_WIRE_DTYPES`) the per-agent residual ``ef`` is
-        required and the call returns ``(S_out, ef_out)``; otherwise it
-        returns ``S_out`` alone.
+        is what DePCA's increasing-consensus schedule uses).  ``mixing``
+        replaces the engine's own ``(L, eta)`` with a traced pair (one
+        step of :meth:`DynamicConsensusEngine.operands`); on ``shard_map``
+        it takes the dense ``all_gather`` round, the one lowering valid for
+        every graph.  On EF wire modes (:data:`EF_WIRE_DTYPES`) the
+        per-agent residual ``ef`` is required and the call returns
+        ``(S_out, ef_out)``; otherwise it returns ``S_out`` alone.
         """
         r = self.K if rounds is None else int(rounds)
         ef_mode = _check_ef(self.wire_dtype, ef)
         if r <= 0:
             return (S, ef) if ef_mode else S
-        if S.shape[0] != self.topology.m:
-            raise ValueError(
-                f"leading (agent) axis {S.shape[0]} != topology m="
-                f"{self.topology.m}")
-        if self.backend == "stacked":
-            L = self._L(S.dtype)
-            if ef_mode:
-                return fastmix_wire_ef(S, ef, L, self.eta, r,
-                                       wire_dtype=self.wire_dtype)
-            if self.wire_dtype is not None:
-                return fastmix_wire(S, L, self.eta, r)
-            if self.variant == "naive":
-                return naive_mix(S, L, r)
-            return fastmix(S, L, self.eta, r)
+        self._check_agents(S, mixing)
+        if self.backend == "shard_map":
+            return self._mix_shard_map(S, r, mixing)
         if self.backend == "pallas":
+            L, eta = self._mixing(mixing, _fused_dtype(S))
             if ef_mode:
-                return self._mix_fused_ef(S, ef, r)
-            return self._mix_fused(S, r)
-        return self._mix_shard_map(S, r)
+                return _fused_mix_ef(S, ef, L, eta, r,
+                                     interpret=self.interpret,
+                                     block_n=self.block_n,
+                                     wire=self.wire_dtype)
+            return _fused_mix(S, L, eta, r, interpret=self.interpret,
+                              block_n=self.block_n,
+                              wire=self.wire_dtype is not None)
+        L, eta = self._mixing(mixing, S.dtype)
+        if ef_mode:
+            return fastmix_wire_ef(S, ef, L, eta, r,
+                                   wire_dtype=self.wire_dtype)
+        if self.wire_dtype is not None:
+            return fastmix_wire(S, L, eta, r)
+        if self.variant == "naive":
+            return naive_mix(S, L, r)
+        return fastmix(S, L, eta, r)
 
     def mix_track(self, S: jax.Array, G: jax.Array, G_prev: jax.Array,
                   rounds: Optional[int] = None, *,
-                  ef: Optional[jax.Array] = None):
+                  ef: Optional[jax.Array] = None, mixing=None):
         """Fused Eqns. (3.1)+(3.2): gossip the subspace-tracked iterate.
 
         Semantically ``mix(tracking_update(S, G, G_prev))`` on every
         backend; the ``pallas`` backend runs the combine inside the fused
         launch (one fewer HBM pass per power iteration), the others fall
-        through to :meth:`mix` on the shared tracking compute site.  EF
-        wire modes require ``ef`` and return ``(S_out, ef_out)``.
+        through to :meth:`mix` on the shared tracking compute site.
+        ``mixing`` as in :meth:`mix`.  EF wire modes require ``ef`` and
+        return ``(S_out, ef_out)``.
         """
         r = self.K if rounds is None else int(rounds)
         ef_mode = _check_ef(self.wire_dtype, ef)
         if self.backend == "pallas" and r > 0:
-            if S.shape[0] != self.topology.m:
-                raise ValueError(
-                    f"leading (agent) axis {S.shape[0]} != topology m="
-                    f"{self.topology.m}")
-            dtype = jnp.float64 if S.dtype == jnp.float64 else jnp.float32
+            self._check_agents(S, mixing)
+            L, eta = self._mixing(mixing, _fused_dtype(S))
             if ef_mode:
                 return _fused_track_mix_ef(
-                    S, G, G_prev, ef, self._L(dtype), self.eta, r,
-                    interpret=self.interpret, block_n=self.block_n,
-                    wire=self.wire_dtype)
-            return _fused_track_mix(S, G, G_prev, self._L(dtype), self.eta,
-                                    r, interpret=self.interpret,
+                    S, G, G_prev, ef, L, eta, r, interpret=self.interpret,
+                    block_n=self.block_n, wire=self.wire_dtype)
+            return _fused_track_mix(S, G, G_prev, L, eta, r,
+                                    interpret=self.interpret,
                                     block_n=self.block_n,
                                     wire=self.wire_dtype is not None)
         from repro.kernels.fastmix import tracking_update
-        return self.mix(tracking_update(S, G, G_prev), rounds=rounds, ef=ef)
+        return self.mix(tracking_update(S, G, G_prev), rounds=rounds, ef=ef,
+                        mixing=mixing)
 
     def apply_mix_track(self, S: jax.Array, W: jax.Array, G_prev: jax.Array,
-                        ops, rounds: Optional[int] = None):
+                        ops, rounds: Optional[int] = None, *, mixing=None):
         """The whole gossip half-iteration, fused: local apply + Eqn. (3.1)
         combine + Eqn. (3.2) gossip -> ``(S_new, G)``.
 
@@ -505,7 +529,8 @@ class ConsensusEngine:
         launches.  Everywhere else (Gram-form data operators, off-TPU
         hosts, f64, non-pallas backends) it is the bit-equal composition
         ``ops.apply`` + :meth:`mix_track` — which on the off-TPU pallas
-        backend IS the poly fallback the acceptance test pins.
+        backend IS the poly fallback the acceptance test pins.  ``mixing``
+        as in :meth:`mix`.
         """
         if self.ef_wire:
             raise ValueError(
@@ -517,46 +542,48 @@ class ConsensusEngine:
         if (self.backend == "pallas" and r > 0 and ops.dense is not None
                 and S.dtype != jnp.float64
                 and _use_pallas_kernel(self.interpret)):
-            if S.shape[0] != self.topology.m:
-                raise ValueError(
-                    f"leading (agent) axis {S.shape[0]} != topology m="
-                    f"{self.topology.m}")
+            self._check_agents(S, mixing)
+            L, eta = self._mixing(mixing, jnp.float32)
             from repro.kernels.fastmix import apply_track_fused
             with jax.named_scope(SCOPE_MIX):
                 S_new, G = apply_track_fused(
-                    ops.dense, W, S, G_prev, self._L(jnp.float32), self.eta,
-                    r, interpret=self.interpret is True,
+                    ops.dense, W, S, G_prev, L, eta, r,
+                    interpret=self.interpret is True,
                     wire_bf16=self.wire_dtype is not None)
             return S_new.astype(S.dtype), G.astype(S.dtype)
         with jax.named_scope(SCOPE_APPLY):
             G = ops.apply(W)
         with jax.named_scope(SCOPE_MIX):
-            return self.mix_track(S, G, G_prev, rounds=rounds), G
+            return self.mix_track(S, G, G_prev, rounds=rounds,
+                                  mixing=mixing), G
 
-    def _mix_fused(self, S: jax.Array, rounds: int) -> jax.Array:
-        dtype = jnp.float64 if S.dtype == jnp.float64 else jnp.float32
-        return _fused_mix(S, self._L(dtype), self.eta, rounds,
-                          interpret=self.interpret, block_n=self.block_n,
-                          wire=self.wire_dtype is not None)
-
-    def _mix_fused_ef(self, S: jax.Array, ef: jax.Array, rounds: int):
-        dtype = jnp.float64 if S.dtype == jnp.float64 else jnp.float32
-        return _fused_mix_ef(S, ef, self._L(dtype), self.eta, rounds,
-                             interpret=self.interpret, block_n=self.block_n,
-                             wire=self.wire_dtype)
-
-    def _mix_shard_map(self, S: jax.Array, rounds: int) -> jax.Array:
-        fn = self._sharded_mix_cache.get(rounds)
+    def _mix_shard_map(self, S: jax.Array, rounds: int, mixing=None):
+        # one jitted program per (rounds, traced?): the engine's structured
+        # round (collective_permute or all_gather), or -- with a traced
+        # (L, eta) -- the dense all_gather round, whose replicated operands
+        # let every same-m graph share the program
+        key = (rounds, mixing is not None)
+        fn = self._sharded_mix_cache.get(key)
         if fn is None:
             from jax import shard_map
             from jax.sharding import PartitionSpec as P
+            from .gossip_shard import _dense_round, fastmix_local
             mesh = _resolve_mesh(self.mesh, self.topology.m, self.axis)
-            fn = jax.jit(shard_map(
-                lambda x: self.local_mix(x, axis=self.axis, rounds=rounds),
-                mesh=mesh, in_specs=P(self.axis), out_specs=P(self.axis),
-                check_vma=False))
-            self._sharded_mix_cache[rounds] = fn
-        return fn(S)
+            axis = self.axis
+            if mixing is None:
+                def body(x):
+                    return self.local_mix(x, axis=axis, rounds=rounds)
+                in_specs = P(axis)
+            else:
+                def body(x, Lrep, etarep):
+                    return fastmix_local(
+                        x, lambda y: _dense_round(y, Lrep, axis), etarep,
+                        rounds)
+                in_specs = (P(axis), P(), P())
+            fn = jax.jit(shard_map(body, mesh=mesh, in_specs=in_specs,
+                                   out_specs=P(axis), check_vma=False))
+            self._sharded_mix_cache[key] = fn
+        return fn(S) if mixing is None else fn(S, *mixing)
 
     # -------------------------------------------- in-shard_map local mixing
     def local_round_fn(self, axis: Optional[str] = None
@@ -609,19 +636,16 @@ class ConsensusEngine:
 class DynamicConsensusEngine:
     """Gossip over a time-varying topology, without retracing the hot path.
 
-    Wraps a :class:`~repro.core.schedule.TopologySchedule`.  Two consumption
-    styles:
-
-    * **eager** — :meth:`mix_at`/:meth:`engine_at` resolve the step's
-      topology to a cached per-topology :class:`ConsensusEngine` (full
-      backend-selection rules apply, including the structured shard_map
-      lowering when the matrix still matches).
-    * **traced** — :meth:`operands` stacks the window's mixing matrices and
-      momenta into ``(T, m, m)`` / ``(T,)`` arrays and :meth:`mix_traced`
-      mixes with them as traced values; this is what ``deepca(schedule=...)``
-      scans over.  All three backends participate: stacked/pallas take
-      ``(L, eta)`` directly, shard_map uses one cached dense ``all_gather``
-      program with ``(L, eta)`` replicated.
+    Wraps a :class:`~repro.core.schedule.TopologySchedule` and adds only
+    what a schedule adds to :class:`ConsensusEngine`: the step's topology
+    (:meth:`topology_at`), its cached static engine (:meth:`engine_at`),
+    and the window's mixing matrices and momenta as ``(T, m, m)`` /
+    ``(T,)`` arrays (:meth:`operands`).  Gossip itself runs on the engines
+    it builds: eagerly per step (``engine_at(t).mix(S)``), or with one
+    step's ``(L, eta)`` as a traced pair (``engine_at(0).mix(S,
+    mixing=(L, eta))``), which is what ``deepca(schedule=...)`` scans
+    over.  Options, their checks and the wire accounting
+    (``bytes_per_round``, ``quantization_floor``) are the built engines'.
     """
 
     schedule: object                    # TopologySchedule (duck-typed)
@@ -635,23 +659,10 @@ class DynamicConsensusEngine:
     wire_dtype: Optional[str] = None    # see ConsensusEngine.wire_dtype
     _engines: dict = dataclasses.field(
         default_factory=dict, init=False, repr=False, compare=False)
-    _traced_cache: dict = dataclasses.field(
-        default_factory=dict, init=False, repr=False, compare=False)
 
     def __post_init__(self):
-        if self.variant not in VARIANTS:
-            raise ValueError(
-                f"variant must be one of {VARIANTS}, got {self.variant!r}")
-        object.__setattr__(self, "backend", resolve_backend(self.backend))
-        if self.wire_dtype not in WIRE_DTYPES:
-            raise ValueError(
-                f"wire_dtype must be one of {WIRE_DTYPES}, got "
-                f"{self.wire_dtype!r}")
-        if self.wire_dtype is not None and self.backend == "shard_map":
-            raise ValueError(
-                "wire_dtype is not supported on the shard_map backend "
-                "(collective rounds run at the mesh's native precision); "
-                "use stacked or pallas")
+        # the step-0 engine checks the options and resolves the backend
+        object.__setattr__(self, "backend", self.engine_at(0).backend)
 
     # ---------------------------------------------------------- per-step
     def topology_at(self, t: int):
@@ -675,11 +686,6 @@ class DynamicConsensusEngine:
                 wire_dtype=self.wire_dtype)
             self._engines[key] = eng
         return eng
-
-    def mix_at(self, S: jax.Array, t: int,
-               rounds: Optional[int] = None) -> jax.Array:
-        """Eager per-step mix (resolves the topology in force at step t)."""
-        return self.engine_at(t).mix(S, rounds=rounds)
 
     def eta_of(self, topology) -> float:
         return _variant_eta(self.variant, topology.lambda2)
@@ -706,140 +712,8 @@ class DynamicConsensusEngine:
 
     @property
     def ef_wire(self) -> bool:
-        """True when this engine's wire mode carries an EF residual."""
-        return self.wire_dtype in EF_WIRE_DTYPES
-
-    def bytes_per_round(self, d: int, k: int) -> int:
-        """Per-agent wire bytes per gossip round; see
-        :meth:`ConsensusEngine.bytes_per_round` (topology-independent, so
-        schedule swaps never change it)."""
-        from repro.kernels.fastmix import WIRE_ITEMSIZE
-        n = int(d) * int(k) * WIRE_ITEMSIZE[self.wire_dtype]
-        if self.wire_dtype == "int8":
-            n += 4
-        return n
-
-    def quantization_floor(self) -> float:
-        """See :meth:`ConsensusEngine.quantization_floor` (wire modes are
-        schedule-independent, so one floor covers the whole window)."""
-        return WIRE_QUANT_FLOOR[self.wire_dtype]
-
-    def mix_traced(self, S: jax.Array, L: jax.Array, eta,
-                   rounds: Optional[int] = None, *,
-                   ef: Optional[jax.Array] = None):
-        """Mix with ``(L, eta)`` as traced values (jit-cache keyed on shape).
-
-        This is the scan-body entry point: callable under an outer trace,
-        with ``L`` one slice of :meth:`operands`' stack.  EF wire modes
-        require ``ef`` and return ``(S_out, ef_out)``.
-        """
-        r = self.K if rounds is None else int(rounds)
-        ef_mode = _check_ef(self.wire_dtype, ef)
-        if r <= 0:
-            return (S, ef) if ef_mode else S
-        if self.backend == "stacked":
-            if ef_mode:
-                return fastmix_wire_ef(S, ef, L.astype(S.dtype), eta, r,
-                                       wire_dtype=self.wire_dtype)
-            if self.wire_dtype is not None:
-                return fastmix_wire(S, L.astype(S.dtype), eta, r)
-            return fastmix(S, L.astype(S.dtype), eta, r)
-        if self.backend == "pallas":
-            if ef_mode:
-                return _fused_mix_ef(S, ef, L, eta, r,
-                                     interpret=self.interpret,
-                                     block_n=self.block_n,
-                                     wire=self.wire_dtype)
-            return _fused_mix(S, L, eta, r, interpret=self.interpret,
-                              block_n=self.block_n,
-                              wire=self.wire_dtype is not None)
-        return self._mix_shard_map_traced(S, L, eta, r)
-
-    def mix_track_traced(self, S: jax.Array, G: jax.Array, G_prev: jax.Array,
-                         L: jax.Array, eta,
-                         rounds: Optional[int] = None, *,
-                         ef: Optional[jax.Array] = None):
-        """Tracked :meth:`mix_traced` — the scan-body DeEPCA gossip call.
-
-        ``pallas`` fuses the subspace-tracking combine into the launch with
-        ``(L, eta)`` still traced (no retrace on graph swap); the other
-        backends compose the shared tracking compute site with the plain
-        traced mix.  EF wire modes require ``ef`` and return
-        ``(S_out, ef_out)``.
-        """
-        r = self.K if rounds is None else int(rounds)
-        ef_mode = _check_ef(self.wire_dtype, ef)
-        if self.backend == "pallas" and r > 0:
-            if ef_mode:
-                return _fused_track_mix_ef(S, G, G_prev, ef, L, eta, r,
-                                           interpret=self.interpret,
-                                           block_n=self.block_n,
-                                           wire=self.wire_dtype)
-            return _fused_track_mix(S, G, G_prev, L, eta, r,
-                                    interpret=self.interpret,
-                                    block_n=self.block_n,
-                                    wire=self.wire_dtype is not None)
-        from repro.kernels.fastmix import tracking_update
-        return self.mix_traced(tracking_update(S, G, G_prev), L, eta,
-                               rounds=rounds, ef=ef)
-
-    def apply_mix_track_traced(self, S: jax.Array, W: jax.Array,
-                               G_prev: jax.Array, ops, L: jax.Array, eta,
-                               rounds: Optional[int] = None):
-        """Traced-operand counterpart of
-        :meth:`ConsensusEngine.apply_mix_track` -> ``(S_new, G)``.
-
-        The fused kernel takes ``(L, eta)`` as traced operands like every
-        other dynamic path — graph swaps never retrace; the composition
-        fallback keeps the bit-equality contract everywhere the kernel
-        does not fire.
-        """
-        if self.ef_wire:
-            raise ValueError(
-                "apply_mix_track_traced does not thread the EF residual; "
-                f"EF wire modes {EF_WIRE_DTYPES} compose ops.apply with "
-                "mix_track_traced(..., ef=) instead (PowerStep does this "
-                "automatically when ef_wire=True)")
-        r = self.K if rounds is None else int(rounds)
-        if (self.backend == "pallas" and r > 0 and ops.dense is not None
-                and S.dtype != jnp.float64
-                and _use_pallas_kernel(self.interpret)):
-            from repro.kernels.fastmix import apply_track_fused
-            with jax.named_scope(SCOPE_MIX):
-                S_new, G = apply_track_fused(
-                    ops.dense, W, S, G_prev, L.astype(jnp.float32), eta, r,
-                    interpret=self.interpret is True,
-                    wire_bf16=self.wire_dtype is not None)
-            return S_new.astype(S.dtype), G.astype(S.dtype)
-        with jax.named_scope(SCOPE_APPLY):
-            G = ops.apply(W)
-        with jax.named_scope(SCOPE_MIX):
-            return (self.mix_track_traced(S, G, G_prev, L, eta,
-                                          rounds=rounds), G)
-
-    def _mix_shard_map_traced(self, S, L, eta, rounds: int):
-        # the dense all_gather round is the only lowering valid for EVERY
-        # graph in a schedule, so the traced shard_map path always uses it;
-        # (L, eta) are replicated operands -> one compiled program per
-        # rounds value, shared by all topologies
-        fn = self._traced_cache.get(rounds)
-        if fn is None:
-            from jax import shard_map
-            from jax.sharding import PartitionSpec as P
-            from .gossip_shard import _dense_round, fastmix_local
-            mesh = _resolve_mesh(self.mesh, self.schedule.topology_at(0).m,
-                                 self.axis)
-            axis = self.axis
-
-            def body(x, Lrep, etarep):
-                return fastmix_local(
-                    x, lambda y: _dense_round(y, Lrep, axis), etarep, rounds)
-
-            fn = jax.jit(shard_map(
-                body, mesh=mesh, in_specs=(P(self.axis), P(), P()),
-                out_specs=P(self.axis), check_vma=False))
-            self._traced_cache[rounds] = fn
-        return fn(S, L, eta)
+        """True when the built engines' wire mode carries an EF residual."""
+        return self.engine_at(0).ef_wire
 
     # -------------------------------------------------------- construction
     @classmethod

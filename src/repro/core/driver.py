@@ -22,6 +22,11 @@ sharing the single :class:`~repro.core.step.PowerStep` body:
     :class:`~repro.core.gossip_shard.DistributedDeEPCA` loops over (agents
     = devices along a named mesh axis).
 
+``scan``, ``traced_scan`` and :meth:`IterationDriver.run_batch` launch one
+program (:meth:`IterationDriver.program`): the same scan body, which
+differs only in where ``(L, eta)`` come from -- a static engine closes
+over its own graph, a schedule scans its per-step operands.
+
 Every substrate that owns its operators statically (scan, traced scan,
 unrolled, run_batch — and run_stream, which resumes windows through
 ``run``) hands the step the engine's fused ``apply_mix_track`` entry point
@@ -144,12 +149,10 @@ class IterationDriver:
     engine: Optional[ConsensusEngine] = None
     dynamic: Optional[DynamicConsensusEngine] = None
     diagnostics: Optional[diagnostics_lib.DiagnosticsSpec] = None
-    _batch_cache: dict = dataclasses.field(
-        default_factory=dict, init=False, repr=False)
-    # per-(substrate, T, kind) cache of jitted single-problem programs:
-    # repeated run() calls on one driver (sequential serving, block-resumed
-    # loops) must not re-trace the T-step scan every time
-    _run_cache: dict = dataclasses.field(
+    # one cache of jitted programs for every compiled launch (run's scan
+    # and traced scan, run_batch cold or resumed): repeated launches on one
+    # driver (serving, resumed windows) must not re-trace the T-step scan
+    _programs: dict = dataclasses.field(
         default_factory=dict, init=False, repr=False)
     # (jitted program, argument shapes) of the latest compiled launch
     _last_launch: Optional[tuple] = dataclasses.field(
@@ -169,13 +172,6 @@ class IterationDriver:
         return (self.diagnostics.names(self.step)
                 if self.diagnostics is not None else ())
 
-    def _launch(self, fn, *args):
-        """Call a cached jitted program, remembering its argument shapes
-        for :meth:`lower_last_launch`."""
-        self._last_launch = (fn, jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args))
-        return fn(*args)
-
     def lower_last_launch(self) -> "jax.stages.Lowered":
         """The lowering of the latest compiled launch (``scan``,
         ``traced_scan`` or :meth:`run_batch`) at the shapes and dtypes it
@@ -186,9 +182,16 @@ class IterationDriver:
         fn, avals = self._last_launch
         return fn.lower(*avals)
 
+    @property
+    def _gossip(self) -> ConsensusEngine:
+        """The engine every substrate gossips through: the static engine,
+        or a schedule's step-0 engine, handed each step's ``(L, eta)``."""
+        return self.engine if self.engine is not None \
+            else self.dynamic.engine_at(0)
+
     def quantization_floor(self) -> float:
         """The engine's wire quantization floor (attached to diag events)."""
-        return (self.engine or self.dynamic).quantization_floor()
+        return self._gossip.quantization_floor()
 
     # ------------------------------------------------------------ running
     def run(self, ops: StackedOperators, W0: jax.Array, *, T: int,
@@ -230,10 +233,11 @@ class IterationDriver:
         if substrate != "unrolled" and self.step.increasing:
             raise ValueError("increasing rounds require the unrolled "
                              "substrate (per-step static round counts)")
-        fn = {"scan": self._run_scan, "traced_scan": self._run_traced_scan,
-              "unrolled": self._run_unrolled}[substrate]
         with tracing.span("driver.run", substrate=substrate, T=int(T)):
-            out = fn(ops, W0, carry, T, t0, dt)
+            if substrate == "unrolled":
+                out = self._run_unrolled(ops, W0, carry, T, t0, dt)
+            else:
+                out = self._run_scan(ops, W0, carry, T, t0, dt, substrate)
             # DriverRun already carries the paper's observables host-side
             # (cumulative gossip rounds, per-iteration contraction bound) —
             # stream them when a sink is installed.
@@ -252,7 +256,7 @@ class IterationDriver:
         ``(d, k)`` of ``W0``) — the cost model behind the telemetry
         ``bytes_on_wire`` field and the bench ``bytes_per_round`` rows."""
         d, k = int(W0.shape[-2]), int(W0.shape[-1])
-        return (self.engine or self.dynamic).bytes_per_round(d, k)
+        return self._gossip.bytes_per_round(d, k)
 
     # -------------------------------------------------- streaming substrate
     def run_stream(self, ticks, W0, *, T: int, t0: int = 0,
@@ -266,7 +270,7 @@ class IterationDriver:
         ``(S, W, G_prev)`` carry with global-iteration accounting continued
         (``t0`` advances by ``T`` per tick), and yields that tick's
         :class:`DriverRun`.  Because the per-problem operators enter the
-        cached jitted programs as *traced operands* (see :meth:`_scan_fn`),
+        cached jitted programs as *traced operands* (see :meth:`program`),
         every tick after the first reuses one compiled program — the
         property that makes warm-start online tracking cheap.
 
@@ -310,9 +314,7 @@ class IterationDriver:
         dt = jnp.result_type(W0.dtype, ops.dtype)
         carry = step.init_carry(ops, W0, dtype=dt)
         S, W, G_prev = carry[:3]
-        eng = self.engine if self.engine is not None \
-            else self.dynamic.engine_at(0)
-        mix = step.make_mix(eng)
+        mix = step.make_mix(self._gossip)
         W0_c = jnp.asarray(W0, dt)
 
         apply_j = jax.jit(lambda V: ops.apply(V))
@@ -376,103 +378,99 @@ class IterationDriver:
     def _form_attrs(form: Optional[str]) -> dict:
         return {} if form is None else {"form": form}
 
-    def _scan_fn(self, T: int, kind: str, form: Optional[str]):
-        """Cached jitted static-topology scan over one problem.
+    def _rates(self, t0: int, T: int, rounds: int) -> np.ndarray:
+        """Prop. 1 contraction bound of each iteration in ``[t0, t0 + T)``
+        at ``rounds`` gossip rounds."""
+        if self.dynamic is not None:
+            return self.dynamic.contraction_rates(t0, T, rounds=rounds)
+        return np.full(T, self.engine.contraction_rate(rounds),
+                       dtype=np.float32)
 
-        Returns ``(fn, warm)``.  The diagnostics spec is part of the cache
-        key: diag-on and diag-off are distinct compiled programs, so
+    def program(self, T: int, kind: str, form: Optional[str], *,
+                batched: bool = False, with_history: bool = True,
+                resume: bool = True):
+        """The jitted program of a compiled launch; returns ``(fn, warm)``.
+
+        ``fn(arr, W0, xs, *carry)`` runs ``T`` iterations of one problem,
+        or with ``batched`` of a ``vmap`` over a leading problem axis of
+        every argument.  ``xs`` is ``None`` on a static engine, whose
+        program closes over its own graph, and ``(Ls, etas)`` on a
+        schedule (:meth:`DynamicConsensusEngine.operands`), scanned one
+        step's ``(L, eta)`` at a time, so graph swaps never retrace.
+        Without ``resume`` the program starts from ``init_carry`` and takes
+        no carry.  It returns ``(carry, hists, diag)``: ``hists`` is
+        ``(S_hist, W_hist)`` with ``with_history`` and ``diag`` the stacked
+        observables with diagnostics on, each ``()`` otherwise.  ``form``
+        (:meth:`_apply_form`) picks the apply's evaluation order.  The
+        cache key holds every argument and the diagnostics spec, so
         toggling diagnostics never invalidates (or perturbs) the off path.
-        ``form`` (:meth:`_apply_form`) is part of it too.
         """
         spec = self.diagnostics
-        key = ("scan", T, kind, form, spec)
-        fn = self._run_cache.get(key)
-        warm = fn is not None
-        telemetry.emit("launch", source="driver.run", substrate="scan",
-                       T=T, kind=kind, warm=warm, **self._form_attrs(form))
-        if fn is None:
-            step, eng = self.step, self.engine
-            mix = step.make_mix(eng)
+        key = (T, kind, form, batched, with_history, resume, spec)
+        fn = self._programs.get(key)
+        if fn is not None:
+            return fn, True
+        step, eng = self.step, self._gossip
 
-            def scan_fn(arr, W0, carry):
-                ops = self._rebuild_ops(kind, form, arr)
-                apply_mix = step.make_apply_mix(eng, ops)
+        def one(arr, W0, xs, *carry):
+            ops = self._rebuild_ops(kind, form, arr)
+            if not resume:
+                carry = step.init_carry(ops, W0)
 
-                def body(c, _):
-                    new_c, ys = step(c, mix, W0, ops.apply,
-                                     apply_mix=apply_mix)
-                    if spec is not None:
-                        ys = ys + (step.measure(spec, new_c, c),)
-                    return new_c, ys
+            def body(c, mixing):
+                new_c, ys = step(
+                    c, step.make_mix(eng, mixing=mixing), W0, ops.apply,
+                    apply_mix=step.make_apply_mix(eng, ops, mixing=mixing))
+                if spec is not None:
+                    ys = ys + (step.measure(spec, new_c, c),)
+                return new_c, ys
 
-                return jax.lax.scan(body, carry, None, length=T)
+            carry, ys = jax.lax.scan(body, carry, xs, length=T)
+            return (carry, ys[:2] if with_history else (),
+                    ys[2] if spec is not None else ())
 
-            fn = self._run_cache[key] = jax.jit(scan_fn)
-        return fn, warm
+        # the lowered module is named after this function (jit_one_static,
+        # ...), so each program keeps the name its device traces and its
+        # compile-cache entries carry
+        one.__name__ = ("scan_fn" if not batched else "one_static"
+                        if self.dynamic is None else "one_dynamic")
+        fn = self._programs[key] = jax.jit(jax.vmap(one) if batched else one)
+        return fn, False
 
-    def _traced_scan_fn(self, T: int, kind: str, form: Optional[str]):
-        """Cached jitted dynamic-schedule scan; ``(Ls, etas)`` are traced.
-        Returns ``(fn, warm)``; see :meth:`_scan_fn` on the cache key."""
-        spec = self.diagnostics
-        key = ("traced_scan", T, kind, form, spec)
-        fn = self._run_cache.get(key)
-        warm = fn is not None
-        telemetry.emit("launch", source="driver.run", substrate="traced_scan",
-                       T=T, kind=kind, warm=warm, **self._form_attrs(form))
-        if fn is None:
-            step, dyn = self.step, self.dynamic
+    def _launch(self, source: str, substrate: str, T: int, kind: str,
+                arr: jax.Array, *args, **build):
+        """The one compiled-launch path: the apply's form, the program
+        (:meth:`program`), its ``launch`` event and ``driver.launch`` span;
+        the argument shapes are kept for :meth:`lower_last_launch`."""
+        form = self._apply_form(kind, arr, T)
+        fn, warm = self.program(T, kind, form, **build)
+        telemetry.emit("launch", source=source, substrate=substrate, T=T,
+                       kind=kind, warm=warm, **self._form_attrs(form))
+        attrs = {}
+        if tracing.enabled():
+            attrs = dict(substrate=substrate, T=int(T), warm=warm,
+                         **self._form_attrs(form))
+        args = (arr,) + args
+        self._last_launch = (fn, jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args))
+        with tracing.span("driver.launch", **attrs):
+            return fn(*args)
 
-            def scan_fn(arr, W0, carry, Ls, etas):
-                ops = self._rebuild_ops(kind, form, arr)
-
-                def body(c, xs):
-                    L_t, eta_t = xs
-                    new_c, ys = step(
-                        c, step.make_mix_traced(dyn, L_t, eta_t), W0,
-                        ops.apply,
-                        apply_mix=step.make_apply_mix_traced(dyn, ops, L_t,
-                                                             eta_t))
-                    if spec is not None:
-                        ys = ys + (step.measure(spec, new_c, c),)
-                    return new_c, ys
-
-                return jax.lax.scan(body, carry, (Ls, etas), length=T)
-
-            fn = self._run_cache[key] = jax.jit(scan_fn)
-        return fn, warm
-
-    def _run_scan(self, ops, W0, carry, T, t0, dt) -> DriverRun:
-        K = self.step.rounds
+    def _run_scan(self, ops, W0, carry, T, t0, dt, substrate) -> DriverRun:
+        """``scan`` and ``traced_scan``: one compiled launch of one problem."""
+        xs = None if self.dynamic is None \
+            else self.dynamic.operands(t0, T, dtype=dt)
         kind = "dense" if ops.dense is not None else "data"
-        form = self._apply_form(kind, ops.array, T)
-        fn, warm = self._scan_fn(T, kind, form)
-        with tracing.span("driver.launch", substrate="scan", T=int(T),
-                          warm=warm, **self._form_attrs(form)):
-            carry, ys = self._launch(fn, ops.array, W0, carry)
-        S_hist, W_hist = ys[0], ys[1]
-        diag = ys[2] if self.diagnostics is not None else None
-        rounds = np.arange(1, T + 1, dtype=np.float32) * float(K)
-        rates = np.full(T, self.engine.contraction_rate(K), dtype=np.float32)
-        return DriverRun(carry, S_hist, W_hist, rounds, rates, diag=diag,
-                         diag_names=self._diag_names())
-
-    def _run_traced_scan(self, ops, W0, carry, T, t0, dt) -> DriverRun:
-        Ls, etas = self.dynamic.operands(t0, T, dtype=dt)
-        kind = "dense" if ops.dense is not None else "data"
-        form = self._apply_form(kind, ops.array, T)
-        fn, warm = self._traced_scan_fn(T, kind, form)
-        with tracing.span("driver.launch", substrate="traced_scan", T=int(T),
-                          warm=warm, **self._form_attrs(form)):
-            carry, ys = self._launch(fn, ops.array, W0, carry, Ls, etas)
-        S_hist, W_hist = ys[0], ys[1]
-        diag = ys[2] if self.diagnostics is not None else None
+        carry, (S_hist, W_hist), diag = self._launch(
+            "driver.run", substrate, T, kind, ops.array, W0, xs, *carry)
         rounds = np.arange(1, T + 1, dtype=np.float32) * float(self.step.rounds)
-        rates = self.dynamic.contraction_rates(t0, T)
-        return DriverRun(carry, S_hist, W_hist, rounds, rates, diag=diag,
+        return DriverRun(carry, S_hist, W_hist, rounds,
+                         self._rates(t0, T, self.step.rounds),
+                         diag=diag if self.diagnostics is not None else None,
                          diag_names=self._diag_names())
 
     def _run_unrolled(self, ops, W0, carry, T, t0, dt) -> DriverRun:
-        step, eng, dyn = self.step, self.engine, self.dynamic
+        step, dyn, eng = self.step, self.dynamic, self._gossip
         spec = self.diagnostics
         S_hist, W_hist, rounds, rates, diag = [], [], [], [], []
         total = 0
@@ -485,21 +483,16 @@ class IterationDriver:
                 t = t0 + i
                 r = step.rounds_at(t)
                 total += r
+                mixing = None
                 if dyn is not None:
                     topo_t = dyn.topology_at(t)
-                    L_t = jnp.asarray(topo_t.mixing, dt)
-                    eta_t = dyn.eta_of(topo_t)
-                    mix = step.make_mix_traced(dyn, L_t, eta_t, rounds=r)
-                    apply_mix = step.make_apply_mix_traced(dyn, ops, L_t,
-                                                           eta_t, rounds=r)
-                    rates.append(float(dyn.contraction_rates(t, 1,
-                                                             rounds=r)[0]))
-                else:
-                    mix = step.make_mix(eng, rounds=r)
-                    apply_mix = step.make_apply_mix(eng, ops, rounds=r)
-                    rates.append(eng.contraction_rate(r))
-                new_carry, (S_t, W_t) = step(carry, mix, W0, ops.apply,
-                                             apply_mix=apply_mix)
+                    mixing = (jnp.asarray(topo_t.mixing, dt),
+                              dyn.eta_of(topo_t))
+                rates.append(float(self._rates(t, 1, r)[0]))
+                new_carry, (S_t, W_t) = step(
+                    carry, step.make_mix(eng, rounds=r, mixing=mixing), W0,
+                    ops.apply, apply_mix=step.make_apply_mix(
+                        eng, ops, rounds=r, mixing=mixing))
                 if spec is not None:
                     diag.append(step.measure(spec, new_carry, carry))
                 carry = new_carry
@@ -562,8 +555,7 @@ class IterationDriver:
         Gram ``X_j^T X_j`` is formed once inside the launch at
         ``HIGHEST`` precision and never kept across launches.
         """
-        backend = (self.engine or self.dynamic).backend
-        if backend == "shard_map":
+        if self._gossip.backend == "shard_map":
             raise ValueError(
                 "run_batch cannot vmap the shard_map backend (devices are "
                 "a physical axis); use stacked/pallas for batched serving")
@@ -589,40 +581,25 @@ class IterationDriver:
                     f"every slot; got shapes {bad}")
         resumed: Tuple[jax.Array, ...] = tuple(carry) if resume else ()
 
-        form = self._apply_form(kind, arr, T)
-        args: Tuple[jax.Array, ...] = (arr, W0)
+        xs = None
         if self.dynamic is not None:
             offs = [0] * B if t0 is None else [int(x) for x in t0]
             if len(offs) != B:
                 raise ValueError(f"t0 has {len(offs)} offsets for {B} "
                                  "problems")
-            ops_all = []
-            for off in offs:
-                Ls_b, etas_b = self.dynamic.operands(off, T, dtype=dt)
-                ops_all.append((Ls_b, etas_b))
-            args += (jnp.stack([o[0] for o in ops_all]),
-                     jnp.stack([o[1] for o in ops_all]))
-        fn, warm = self._batch_fn(T, kind, form, with_history,
-                                  dynamic=self.dynamic is not None,
-                                  resume=resume)
-        attrs = {}
-        if tracing.enabled():
-            attrs = dict(substrate="vmap", T=int(T), warm=warm,
-                         **self._form_attrs(form))
-        with tracing.span("driver.launch", **attrs):
-            out = self._launch(fn, *args, *resumed)
-        carry, hists, dvals = out
+            Ls, etas = zip(*(self.dynamic.operands(off, T, dtype=dt)
+                             for off in offs))
+            xs = (jnp.stack(Ls), jnp.stack(etas))
+        carry, hists, dvals = self._launch(
+            "driver.run_batch", "vmap", T, kind, arr, W0, xs, *resumed,
+            batched=True, with_history=with_history, resume=resume)
         diag = dvals if self.diagnostics is not None else None
         S, W, G_prev = carry[:3]
         extras = tuple(carry[3:])
         names = self._diag_names()
         if telemetry.enabled():
             K = step.rounds
-            if self.dynamic is not None:
-                rates = self.dynamic.contraction_rates(offs[0], T)
-            else:
-                rates = np.full(T, self.engine.contraction_rate(K),
-                                dtype=np.float32)
+            rates = self._rates(offs[0] if xs is not None else 0, T, K)
             rounds = np.arange(1, T + 1, dtype=np.float32) * float(K)
             telemetry.emit_iterations(
                 "driver.run_batch", 0, rounds, rates, batch=B,
@@ -656,64 +633,6 @@ class IterationDriver:
             raise ValueError(f"mixed operator kinds in batch: {kinds}")
         kind = kinds.pop()
         return kind, jnp.stack([o.array for o in ops_batch])
-
-    def _batch_fn(self, T: int, kind: str, form: Optional[str],
-                  with_history: bool, dynamic: bool, resume: bool = False):
-        """Cached jitted vmapped program; ``form`` (``"gram"``/``"data"``
-        for data operators, ``None`` for dense) picks the apply's
-        evaluation order.  Returns ``(fn, warm)``."""
-        spec = self.diagnostics
-        key = (T, kind, form, with_history, dynamic, resume, spec)
-        fn = self._batch_cache.get(key)
-        warm = fn is not None
-        telemetry.emit("launch", source="driver.run_batch", substrate="vmap",
-                       T=T, kind=kind, warm=warm, **self._form_attrs(form))
-        if fn is not None:
-            return fn, warm
-        step, eng, dyn = self.step, self.engine, self.dynamic
-
-        def split_ys(carry, ys):
-            hists = (ys[0], ys[1]) if with_history else ()
-            return carry, hists, (ys[2] if spec is not None else ())
-
-        def one_static(arr, W0_b, *carry_in):
-            ops_b = self._rebuild_ops(kind, form, arr)
-            carry = carry_in if resume else step.init_carry(ops_b, W0_b)
-            mix = step.make_mix(eng)
-            apply_mix = step.make_apply_mix(eng, ops_b)
-
-            def body(c, _):
-                new_c, ys = step(c, mix, W0_b, ops_b.apply,
-                                 apply_mix=apply_mix)
-                if spec is not None:
-                    ys = ys + (step.measure(spec, new_c, c),)
-                return new_c, ys
-
-            carry, ys = jax.lax.scan(body, carry, None, length=T)
-            return split_ys(carry, ys)
-
-        def one_dynamic(arr, W0_b, Ls_b, etas_b, *carry_in):
-            ops_b = self._rebuild_ops(kind, form, arr)
-            carry = carry_in if resume else step.init_carry(ops_b, W0_b)
-
-            def body(c, xs):
-                L_t, eta_t = xs
-                new_c, ys = step(
-                    c, step.make_mix_traced(dyn, L_t, eta_t), W0_b,
-                    ops_b.apply,
-                    apply_mix=step.make_apply_mix_traced(dyn, ops_b, L_t,
-                                                         eta_t))
-                if spec is not None:
-                    ys = ys + (step.measure(spec, new_c, c),)
-                return new_c, ys
-
-            carry, ys = jax.lax.scan(body, carry, (Ls_b, etas_b),
-                                     length=T)
-            return split_ys(carry, ys)
-
-        fn = jax.jit(jax.vmap(one_dynamic if dynamic else one_static))
-        self._batch_cache[key] = fn
-        return fn, warm
 
     # --------------------------------------------------- shard_map builders
     def sharded_step_fn(self, mesh, axis: str, engine: ConsensusEngine,

@@ -16,12 +16,12 @@ the *algorithmic* degrees of freedom as data:
 
 and :meth:`PowerStep.__call__` is the ONE definition of the iteration body.
 Substrates differ only in the ``mix`` and ``apply_fn`` callables they hand
-it — a stacked ``ConsensusEngine.mix_track``, a traced-operand
-``mix_track_traced`` inside a scan, or an ``engine.local_mix_track`` on a
-``(1, d, k)`` slice inside ``shard_map``.  The actual tracking arithmetic
-lives in :func:`repro.kernels.fastmix.tracking_update` (shared with the
-fused Pallas kernel), so the whole repo has exactly one tracking compute
-site.
+it — a stacked ``ConsensusEngine.mix_track`` (on the engine's own graph, or
+on a schedule's traced ``(L, eta)`` inside a scan), or an
+``engine.local_mix_track`` on a ``(1, d, k)`` slice inside ``shard_map``.
+The actual tracking arithmetic lives in
+:func:`repro.kernels.fastmix.tracking_update` (shared with the fused
+Pallas kernel), so the whole repo has exactly one tracking compute site.
 
 :class:`repro.core.driver.IterationDriver` runs a step under each substrate;
 :func:`repro.core.algorithms.deepca` / ``depca`` are thin wrappers over it.
@@ -295,57 +295,36 @@ class PowerStep:
         from repro.runtime.diagnostics import diag_vector
         return diag_vector(spec, self, new_carry, old_carry)
 
-    def make_mix(self, engine, rounds: int = None):
-        """Stacked-form ``mix`` callable for one iteration on a static
-        :class:`~repro.core.consensus.ConsensusEngine`.  For ``ef_wire``
-        steps the callable takes/returns the EF residual as well."""
+    def make_mix(self, engine, rounds: int = None, mixing=None):
+        """Stacked-form ``mix`` callable for one iteration on a
+        :class:`~repro.core.consensus.ConsensusEngine`; ``mixing`` is one
+        step's traced ``(L, eta)`` of a schedule, ``None`` for the engine's
+        own graph.  For ``ef_wire`` steps the callable takes/returns the EF
+        residual as well."""
         r = self.rounds if rounds is None else rounds
         if self.ef_wire:
             if self.track:
                 return lambda S, G, G_prev, ef: engine.mix_track(
-                    S, G, G_prev, rounds=r, ef=ef)
-            return lambda S, G, G_prev, ef: engine.mix(G, rounds=r, ef=ef)
+                    S, G, G_prev, rounds=r, ef=ef, mixing=mixing)
+            return lambda S, G, G_prev, ef: engine.mix(G, rounds=r, ef=ef,
+                                                       mixing=mixing)
         if self.track:
-            return lambda S, G, G_prev: engine.mix_track(S, G, G_prev,
-                                                         rounds=r)
-        return lambda S, G, G_prev: engine.mix(G, rounds=r)
+            return lambda S, G, G_prev: engine.mix_track(
+                S, G, G_prev, rounds=r, mixing=mixing)
+        return lambda S, G, G_prev: engine.mix(G, rounds=r, mixing=mixing)
 
-    def make_mix_traced(self, dynamic, L, eta, rounds: int = None):
-        """Traced-operand ``mix`` for one scan step on a
-        :class:`~repro.core.consensus.DynamicConsensusEngine`."""
-        r = self.rounds if rounds is None else rounds
-        if self.ef_wire:
-            if self.track:
-                return lambda S, G, G_prev, ef: dynamic.mix_track_traced(
-                    S, G, G_prev, L, eta, rounds=r, ef=ef)
-            return lambda S, G, G_prev, ef: dynamic.mix_traced(
-                G, L, eta, rounds=r, ef=ef)
-        if self.track:
-            return lambda S, G, G_prev: dynamic.mix_track_traced(
-                S, G, G_prev, L, eta, rounds=r)
-        return lambda S, G, G_prev: dynamic.mix_traced(G, L, eta, rounds=r)
-
-    def make_apply_mix(self, engine, ops, rounds: int = None):
-        """Fused ``apply_mix`` callable for one iteration on a static
-        engine, or ``None`` for non-tracking steps (DePCA gossips the raw
-        power step; there is nothing to fuse the apply *into*) and for
-        EF-wire steps (the EF residual threads through the two-call
-        composition; the dense apply→track→mix kernel has no EF mirror)."""
+    def make_apply_mix(self, engine, ops, rounds: int = None, mixing=None):
+        """Fused ``apply_mix`` callable for one iteration (``mixing`` as in
+        :meth:`make_mix`), or ``None`` for non-tracking steps (DePCA
+        gossips the raw power step; there is nothing to fuse the apply
+        *into*) and for EF-wire steps (the EF residual threads through the
+        two-call composition; the dense apply→track→mix kernel has no EF
+        mirror)."""
         if not self.track or self.ef_wire:
             return None
         r = self.rounds if rounds is None else rounds
-        return lambda S, W, G_prev: engine.apply_mix_track(S, W, G_prev,
-                                                           ops, rounds=r)
-
-    def make_apply_mix_traced(self, dynamic, ops, L, eta,
-                              rounds: int = None):
-        """Traced-operand ``apply_mix`` for one scan step on a dynamic
-        engine (``None`` for non-tracking and EF-wire steps)."""
-        if not self.track or self.ef_wire:
-            return None
-        r = self.rounds if rounds is None else rounds
-        return lambda S, W, G_prev: dynamic.apply_mix_track_traced(
-            S, W, G_prev, ops, L, eta, rounds=r)
+        return lambda S, W, G_prev: engine.apply_mix_track(
+            S, W, G_prev, ops, rounds=r, mixing=mixing)
 
 
 def mean_basis(W: jax.Array) -> jax.Array:

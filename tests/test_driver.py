@@ -112,7 +112,7 @@ def test_driver_matches_legacy_traced_scan():
         S, W, G_prev = carry
         G = apply(W)
         S_new = S + G - G_prev
-        S_new = dyn.mix_traced(S_new, L_t, eta_t)
+        S_new = dyn.engine_at(0).mix(S_new, mixing=(L_t, eta_t))
         W_new = sign_adjust(_qr(S_new), W0)
         return (S_new, W_new, G), (S_new, W_new)
 
@@ -154,8 +154,9 @@ def test_driver_matches_legacy_unrolled():
     for t in range(T):
         G = apply(W_stack)
         topo_t = dyn.topology_at(t)
-        G = dyn.mix_traced(G, jnp.asarray(topo_t.mixing, jnp.float32),
-                           dyn.eta_of(topo_t), rounds=K)
+        G = dyn.engine_at(0).mix(
+            G, rounds=K, mixing=(jnp.asarray(topo_t.mixing, jnp.float32),
+                                 dyn.eta_of(topo_t)))
         W_stack = sign_adjust(_qr(G), W0)
     res_d = depca(ops, None, W0, k=2, T=T, K=K, U=U, backend="stacked",
                   schedule=sched)
@@ -273,24 +274,46 @@ def test_run_batch_matches_python_loop():
                                       np.asarray(ref.carry[1]))
 
 
-@pytest.mark.parametrize("substrate", ["scan", "run_batch"])
+@pytest.mark.parametrize("substrate", [
+    "scan", "run_batch", "traced_scan", "run_batch_t0", "run_batch_resume"])
 def test_lower_last_launch_is_the_launched_program(substrate):
     """The lowering ``lower_last_launch`` returns, compiled and called on
     the launch's own arguments, reproduces the launch bit for bit."""
     ops, _, W0 = _setup()
     topo = erdos_renyi(8, p=0.6, seed=2)
-    driver = IterationDriver(
-        step=PowerStep.for_algorithm("deepca", 4),
-        engine=ConsensusEngine.for_algorithm("deepca", topo, K=4,
-                                             backend="stacked"))
+    step = PowerStep.for_algorithm("deepca", 4)
+    if substrate in ("traced_scan", "run_batch_t0"):
+        dyn = DynamicConsensusEngine.for_algorithm(
+            "deepca", TopologySchedule.periodic_rewiring(8, p=0.6, seed=0,
+                                                         period=2),
+            K=4, backend="stacked")
+        driver = IterationDriver(step=step, dynamic=dyn)
+    else:
+        driver = IterationDriver(
+            step=step, engine=ConsensusEngine.for_algorithm(
+                "deepca", topo, K=4, backend="stacked"))
     with pytest.raises(RuntimeError, match="no compiled program"):
         driver.lower_last_launch()
+    carry0 = step.init_carry(ops, W0)
     if substrate == "scan":
         W = driver.run(ops, W0, T=5).carry[1]
-        args = (ops.array, W0, driver.step.init_carry(ops, W0))
-    else:
+        args = (ops.array, W0, None) + carry0
+    elif substrate == "traced_scan":
+        W = driver.run(ops, W0, T=5, t0=3, substrate="traced_scan").carry[1]
+        args = (ops.array, W0, dyn.operands(3, 5)) + carry0
+    elif substrate == "run_batch":
         W = driver.run_batch([ops], W0[None], T=5).W
-        args = (ops.array[None], W0[None])
+        args = (ops.array[None], W0[None], None)
+    elif substrate == "run_batch_t0":
+        W = driver.run_batch([ops, ops], jnp.stack([W0, W0]), T=5,
+                             t0=[1, 4]).W
+        Ls, etas = zip(*(dyn.operands(t, 5) for t in (1, 4)))
+        args = (jnp.stack([ops.array] * 2), jnp.stack([W0, W0]),
+                (jnp.stack(Ls), jnp.stack(etas)))
+    else:
+        carry = driver.run_batch([ops], W0[None], T=3).carries
+        W = driver.run_batch([ops], W0[None], T=5, carry=carry).W
+        args = (ops.array[None], W0[None], None) + tuple(carry)
     carry = driver.lower_last_launch().compile()(*args)[0]
     np.testing.assert_array_equal(np.asarray(carry[1]), np.asarray(W))
 

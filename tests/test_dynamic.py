@@ -106,7 +106,8 @@ def test_structure_checks_reject_degraded_graphs():
 
 # ------------------------------------------------- dynamic engine parity
 def test_dynamic_engine_matches_per_step_static():
-    """mix_traced / mix_at == a fresh static engine per step (stacked+pallas)."""
+    """Traced (L, eta) / engine_at mixing == a fresh static engine per step
+    (stacked+pallas)."""
     base = erdos_renyi(8, p=0.5, seed=0)
     sched = TopologySchedule.edge_dropout(base, 0.25, seed=4)
     rng = np.random.default_rng(0)
@@ -119,13 +120,14 @@ def test_dynamic_engine_matches_per_step_static():
         ref = ConsensusEngine(sched.topology_at(t), K=6,
                               backend="stacked").mix(S)
         for dyn, tol in ((dyn_s, 1e-5), (dyn_p, 2e-4)):
-            got_tr = dyn.mix_traced(S, Ls[t], etas[t])
-            got_ea = dyn.mix_at(S, t)
+            got_tr = dyn.engine_at(0).mix(S, mixing=(Ls[t], etas[t]))
+            got_ea = dyn.engine_at(t).mix(S)
             assert float(jnp.max(jnp.abs(got_tr - ref))) < tol, t
             assert float(jnp.max(jnp.abs(got_ea - ref))) < tol, t
         # mean preservation holds per-step under the schedule (Prop. 1)
         np.testing.assert_allclose(
-            np.mean(np.asarray(dyn_s.mix_traced(S, Ls[t], etas[t])), axis=0),
+            np.mean(np.asarray(dyn_s.engine_at(0).mix(
+                S, mixing=(Ls[t], etas[t]))), axis=0),
             np.mean(np.asarray(S), axis=0), atol=1e-4)
 
 
@@ -309,9 +311,9 @@ _SCRIPT = textwrap.dedent("""
                                      backend="shard_map", mesh=mesh)
     Ls, etas = dyn_ref.operands(0, 6)
     for t in range(6):
-        ref = dyn_ref.mix_traced(S, Ls[t], etas[t])
-        got_tr = dyn_shm.mix_traced(S, Ls[t], etas[t])
-        got_ea = dyn_shm.mix_at(S, t)
+        ref = dyn_ref.engine_at(0).mix(S, mixing=(Ls[t], etas[t]))
+        got_tr = dyn_shm.engine_at(0).mix(S, mixing=(Ls[t], etas[t]))
+        got_ea = dyn_shm.engine_at(t).mix(S)
         e1 = float(jnp.max(jnp.abs(got_tr - ref)))
         e2 = float(jnp.max(jnp.abs(got_ea - ref)))
         assert e1 < 2e-4 and e2 < 2e-4, (t, e1, e2)

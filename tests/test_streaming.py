@@ -136,7 +136,7 @@ def test_run_stream_is_sequenced_resumed_runs():
         np.testing.assert_array_equal(np.asarray(run.carry[1]),
                                       np.asarray(ref.carry[1]))
         carry, t0 = ref.carry, t0 + 2
-    assert len(driver._run_cache) == 1      # one compiled program, N ticks
+    assert len(driver._programs) == 1       # one compiled program, N ticks
 
 
 def test_tracker_state_is_deepca_resumable():

@@ -153,9 +153,10 @@ def test_w8a_batch_applies_the_gram_on_v5e(one_chip, monkeypatch):
         step=PowerStep.for_algorithm("deepca", 8),
         engine=ConsensusEngine.for_algorithm(
             "deepca", erdos_renyi(m, p=0.5, seed=0), K=8, backend="pallas"))
-    fn, _ = driver._batch_fn(T, "data", "gram", False, dynamic=False)
+    fn, _ = driver.program(T, "data", "gram", batched=True,
+                           with_history=False, resume=False)
     lowered = fn.lower(_spec((B, m, n, d), one_chip),
-                       _spec((B, d, k), one_chip))
+                       _spec((B, d, k), one_chip), None)
     names = set(re.findall(r'kernel_name = "(\w+)"', lowered.as_text()))
     assert "_fastmix_track_kernel" in names
     assert not any("apply_track" in name for name in names)

@@ -154,8 +154,8 @@ def test_ef_modes_refuse_apply_mix_fusion_and_shard_map():
     dyn = DynamicConsensusEngine(
         schedule=TopologySchedule.constant(topo), K=3, wire_dtype="fp8")
     with pytest.raises(ValueError, match="apply_mix_track"):
-        dyn.apply_mix_track_traced(S, S, S, lambda W: W,
-                                   jnp.asarray(topo.mixing), 0.3)
+        dyn.engine_at(0).apply_mix_track(
+            S, S, S, lambda W: W, mixing=(jnp.asarray(topo.mixing), 0.3))
     for wire in EF_WIRE_DTYPES:
         with pytest.raises(ValueError, match="shard_map"):
             ConsensusEngine(topo, K=3, backend="shard_map", wire_dtype=wire)
