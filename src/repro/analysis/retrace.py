@@ -264,9 +264,15 @@ def _build_fleet_warm():
 
     The warm-up phase deliberately exercises every eager program the
     fleet can reach (both shape buckets' base windows, the vmapped
-    restart rebase, the escalation window, the stats program, and the
+    restart rebase, the escalation window, the stats program, each
+    bucket's slot-stack program, the row pad of each row count, and the
     join/leave scatter ops) so the counted phase proves the
     membership-churn-never-retraces contract, not first-call compiles.
+    Steady churn must not recompile the stack program either: it takes
+    the whole pool, free slots riding a filler, and a truth and its mask
+    for every slot, so a leave with no rejoin, a join with other rows
+    into the same bucket and a tenant that starts giving a truth keep its
+    arguments' count and shapes.
     """
     from repro.streaming import (DriftPolicy, SlowRotationStream,
                                  TrackerFleet)
@@ -283,26 +289,39 @@ def _build_fleet_warm():
                             rate=0.05)
     sb = SlowRotationStream(m=m, d=16, k=3, n_per_agent=36, seed=1,
                             rate=0.05)          # second shape bucket
+    sc = SlowRotationStream(m=m, d=16, k=3, n_per_agent=18, seed=2,
+                            rate=0.05)          # a's bucket, other rows
+    streams = {"a": sa, "b": sb, "b2": sb, "c": sc}
+    truthless = {"c"}
     fleet.join("a", sa.init_W0(), n=20)
     fleet.join("b", sb.init_W0(), n=36)
+    fleet.join("c", sc.init_W0(), n=18)
 
     def items(t):
         # whatever the current membership is, feed exactly those tenants
-        return {tid: (sa if tid == "a" else sb).tick(t)
-                for tid in fleet.tenants}
+        ticks = {tid: streams[tid].tick(t) for tid in fleet.tenants}
+        return {tid: tk.ops if tid in truthless else tk
+                for tid, tk in ticks.items()}
 
     def warmup():
-        fleet.tick(items(0))
-        fleet.tick(items(1))        # restart + escalation programs
+        fleet.tick(items(0))        # escalation programs
+        fleet.tick(items(1))        # the drift baselines start
+        fleet.tick(items(2))        # both buckets' restart programs
         fleet.leave("b")            # churn: evict ...
         fleet.join("b2", sb.init_W0(), n=36)   # ... re-admit same slot
-        fleet.tick(items(2))
+        fleet.tick(items(3))
 
     def steady():
         fleet.leave("b2")
         fleet.join("b", sb.init_W0(), n=36)
-        for t in (3, 4):
-            fleet.tick(items(t))
+        fleet.tick(items(4))
+        fleet.leave("c")            # a leave with no rejoin
+        fleet.tick(items(5))
+        streams["c"] = sa           # a join with other rows, same bucket
+        fleet.join("c", sa.init_W0(), n=20)
+        fleet.tick(items(6))
+        truthless.clear()           # c starts giving a truth
+        fleet.tick(items(7))
 
     return warmup, steady
 

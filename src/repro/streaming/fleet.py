@@ -22,8 +22,9 @@ launch count per tick is bounded by the pass structure (base window,
 optional restart re-run, up to ``max_escalations`` escalation windows),
 never by the tenant count.  Tenant admission/eviction is a **slot pool**
 per bucket: join/leave scatters a fresh-tracker (or vacated) state into a
-free slot (:func:`scatter_carry`) without changing the batch shape, so
-fleet membership churn causes ZERO retraces (pinned by the ``fleet-warm``
+free slot (:func:`scatter_carry`) without changing the batch shape, and
+each tick stacks the whole pool (:func:`stack_slots`), so fleet
+membership churn causes ZERO retraces (pinned by the ``fleet-warm``
 retrace contract).  Vacated slots keep riding as inert fillers on a copy
 of an active tenant's operators — real, finite dynamics, so the
 max-over-batch diagnostics reduction never sees garbage.
@@ -50,6 +51,7 @@ static-engine substrate).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Dict, List, NamedTuple, Optional, Tuple
 
@@ -101,6 +103,30 @@ def scatter_carry(carry: Carry, slot: int, values: Carry) -> Carry:
                  for c, v in zip(carry, values))
 
 
+@functools.partial(jax.jit, static_argnames="n_pad")
+def pad_rows(X: jax.Array, *, n_pad: int) -> jax.Array:
+    """One tenant's ``(m, n, d)`` data rows zero-padded to ``n_pad`` (exact:
+    zero rows do not change ``X^T X``); one compiled program per ``n``."""
+    return jnp.pad(X, ((0, 0), (0, n_pad - X.shape[1]), (0, 0)))
+
+
+@jax.jit
+def stack_slots(arrs, Q_prev: jax.Array, Us, has_U: jax.Array):
+    """A bucket's slot-stacked operators and truths in ONE program.
+
+    ``arrs`` holds one operator array per slot, all of the bucket's
+    shape (a free slot repeats its filler); ``Us`` one ``(d, k)`` truth
+    per slot and ``has_U`` the ``(C,)`` mask of the slots whose tenant
+    gave one, the rest taking their ``Q_prev``.  The arguments' count and
+    shapes are the bucket's pool, not its membership: churn compiles
+    nothing.
+    """
+    U = jnp.stack(Us)
+    dt = jnp.result_type(Q_prev, U)
+    return jnp.stack(arrs), jnp.where(has_U[:, None, None], U.astype(dt),
+                                      Q_prev.astype(dt))
+
+
 class TenantReport(NamedTuple):
     """Per-tenant outcome of one fleet tick (mirror of
     :class:`~repro.streaming.tracker.TickReport`, minus the trace)."""
@@ -134,6 +160,7 @@ class FleetTickReport(NamedTuple):
     escalations: int            # tenant escalation windows this tick
     slot_windows: int           # slot-windows run: capacity per launch
     useful_slot_windows: int    # of them, those a tenant keeps
+    stack_cold: int             # first stack launches of a bucket's pool
 
 
 @dataclasses.dataclass
@@ -156,6 +183,7 @@ class _Bucket:
     Q_prev: jax.Array           # (C, d, k) previous-tick mean bases (a
                                 # joiner's W0): the tick's sign reference
     slots: List[Optional[str]]  # tenant id per slot, None = free
+    no_U: jax.Array             # (d, k) zeros: a truthless slot's truth
 
 
 class TrackerFleet:
@@ -227,7 +255,11 @@ class TrackerFleet:
         self.stats = {"ticks": 0, "windows": 0, "warm_launches": 0,
                       "cold_launches": 0, "joins": 0, "leaves": 0,
                       "restarts": 0, "escalations": 0, "slo_breaches": 0,
-                      "slot_windows": 0, "useful_slot_windows": 0}
+                      "slot_windows": 0, "useful_slot_windows": 0,
+                      "stack_cold": 0}
+        # (bucket, capacity) pools whose stack program ran, apart from the
+        # window programs' signatures
+        self._stack_pools: set = set()
         self._rebase_cache: dict = {}
 
     # ---------------------------------------------------------- bucketing
@@ -245,15 +277,6 @@ class TrackerFleet:
         else:
             n_pad = int(d)
         return (kind, self.m, int(d), n_pad, int(k), self.T_tick)
-
-    def _pad_ops(self, ops: StackedOperators, key: tuple) -> jax.Array:
-        kind, _, d, n_pad, _, _ = key
-        if kind == "dense":
-            return ops.array
-        n = ops.data.shape[1]
-        if n == n_pad:
-            return ops.data
-        return jnp.pad(ops.data, ((0, 0), (0, n_pad - n), (0, 0)))
 
     # ---------------------------------------------------------- membership
     @property
@@ -325,7 +348,7 @@ class TrackerFleet:
         carry = tuple(zero for _ in range(self.driver.step.carry_slots))
         Q = jnp.broadcast_to(W0.astype(dt), (C, d, k))
         return _Bucket(key=key, capacity=C, carry=carry, Q_prev=Q,
-                       slots=[None] * C)
+                       slots=[None] * C, no_U=jnp.zeros((d, k), dt))
 
     def _grow_bucket(self, bkt: _Bucket) -> None:
         # a full pool doubles: one cold compile at the new batch shape
@@ -433,7 +456,8 @@ class TrackerFleet:
         latency_ms = (time.perf_counter() - tic_all) * 1e3
         delta = {k: self.stats[k] - before[k]
                  for k in ("windows", "restarts", "escalations",
-                           "slot_windows", "useful_slot_windows")}
+                           "slot_windows", "useful_slot_windows",
+                           "stack_cold")}
         report = FleetTickReport(
             tick=self._ticks, tenants=reports,
             warm_launches=self._tick_warm, cold_launches=self._tick_cold,
@@ -446,18 +470,27 @@ class TrackerFleet:
         self._ticks += 1
         return report
 
-    def _tick_bucket(self, bkt: _Bucket, active, items,
-                     reports: Dict[str, TenantReport]) -> None:
-        pol = self.policy
-        kind = bkt.key[0]
-        tic = time.perf_counter()
+    def _stack(self, bkt: _Bucket, active, items):
+        """The bucket's slot-stacked operators ``(C, m, n_pad, d)`` and
+        truth stack ``(C, d, k)``, plus which tenants gave a truth: each
+        ragged tenant's rows padded by :func:`pad_rows`, then one
+        :func:`stack_slots` dispatch over the whole pool.
 
-        # -- assemble the slot-stacked operators: active slots carry their
-        # tenant's zero-row-padded data; free slots ride a copy of the
-        # first active tenant's (real, finite dynamics — the max-over-batch
-        # diagnostics reduction must never see a QR of zeros)
-        with tracing.span("fleet.stack"):
-            payloads = {}
+        Active slots carry their tenant's zero-row-padded operators; free
+        slots ride a copy of the first active tenant's (real, finite
+        dynamics — the max-over-batch diagnostics reduction must never see
+        a QR of zeros); slots without a truth carry ``Q_prev``'s.
+        """
+        kind, _, _, n_pad, _, _ = bkt.key
+        pool = (bkt.key, bkt.capacity)
+        cold = pool not in self._stack_pools
+        self._stack_pools.add(pool)
+        self.stats["stack_cold"] += int(cold)
+        attrs = dict(stack_cold=int(cold)) if tracing.enabled() else {}
+        with tracing.span("fleet.stack", **attrs):
+            arrs = [None] * bkt.capacity
+            Us = [bkt.no_U] * bkt.capacity
+            has_U = np.zeros(bkt.capacity, bool)
             for s, tid in active:
                 item = items[tid]
                 if isinstance(item, StackedOperators):
@@ -466,18 +499,27 @@ class TrackerFleet:
                     ops, U = item.ops, getattr(item, "U", None)
                 else:
                     ops, U = item[0], (item[1] if len(item) > 1 else None)
-                payloads[s] = (self._pad_ops(ops, bkt.key), U)
-            filler_arr, _ = payloads[active[0][0]]
-            arrs = [payloads[s][0] if s in payloads else filler_arr
-                    for s in range(bkt.capacity)]
-            arr = jnp.stack(arrs)
-            ops_b = (StackedOperators(dense=arr) if kind == "dense"
-                     else StackedOperators(data=arr))
-            U_b = jnp.stack([
-                (payloads[s][1] if s in payloads
-                 and payloads[s][1] is not None else bkt.Q_prev[s])
-                for s in range(bkt.capacity)])
-        has_U = {tid: payloads[s][1] is not None for s, tid in active}
+                a = ops.array
+                if kind == "data" and a.shape[1] != n_pad:
+                    a = pad_rows(a, n_pad=n_pad)
+                arrs[s] = a
+                if U is not None:
+                    Us[s], has_U[s] = U, True
+            filler = arrs[active[0][0]]
+            arr, U_b = stack_slots(
+                tuple(filler if a is None else a for a in arrs),
+                bkt.Q_prev, tuple(Us), has_U)
+        return arr, U_b, {tid: bool(has_U[s]) for s, tid in active}
+
+    def _tick_bucket(self, bkt: _Bucket, active, items,
+                     reports: Dict[str, TenantReport]) -> None:
+        pol = self.policy
+        kind = bkt.key[0]
+        tic = time.perf_counter()
+
+        arr, U_b, has_U = self._stack(bkt, active, items)
+        ops_b = (StackedOperators(dense=arr) if kind == "dense"
+                 else StackedOperators(data=arr))
 
         def stats(carry):
             with tracing.span("fleet.stats"):

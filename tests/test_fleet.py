@@ -354,6 +354,115 @@ def test_serve_fleet_workload_threaded_ingest():
     assert blas_threads() == before
 
 
+# ------------------------------------------------------------ slot stacking
+def _eager_stack(bkt, active, items):
+    """The slot stack built one eager op at a time: each tenant's rows
+    padded alone, free slots on the first active tenant's, slots without
+    a truth on their ``Q_prev``."""
+    kind, n_pad = bkt.key[0], bkt.key[3]
+    payloads = {}
+    for s, tid in active:
+        ops, U = items[tid]
+        payloads[s] = (ops.array if kind == "dense"
+                       else _pad(ops, n_pad).data, U)
+    filler = payloads[active[0][0]][0]
+    arr = jnp.stack([payloads[s][0] if s in payloads else filler
+                     for s in range(bkt.capacity)])
+    U_b = jnp.stack([payloads[s][1] if s in payloads
+                     and payloads[s][1] is not None else bkt.Q_prev[s]
+                     for s in range(bkt.capacity)])
+    return arr, U_b
+
+
+@pytest.mark.parametrize("kind, ns, gone, truths", [
+    ("data", (14, 10, 16, 12), (), ()),
+    ("dense", (8, 8), (), ()),
+    ("data", (14, 10, 12, 16, 10), ("t1", "t3"), ()),
+    ("data", (12, 16, 10), ("t0",), ("t2",)),
+    ("dense", (8, 8, 8), (), ("t0", "t2")),
+], ids=["data-full", "dense-fillers", "data-fillers", "data-some-truths",
+        "dense-some-truths"])
+def test_stack_program_bit_equal_to_eager(kind, ns, gone, truths):
+    """One ``stack_slots`` dispatch builds the bucket's operators and
+    truth stack bit for bit as the per-slot eager construction did —
+    ragged rows, free filler slots (a tenant that left mid-pool), and
+    truths from some tenants only."""
+    m, d, k = 4, 8, 2
+    fleet = TrackerFleet(k=k, T_tick=2, K=2, slots=8, policy=PASSIVE,
+                         topology=erdos_renyi(m, p=0.6, seed=3),
+                         backend="stacked")
+    items = {}
+    for i, n in enumerate(ns):
+        s = SlowRotationStream(m=m, d=d, k=k, n_per_agent=n, seed=i,
+                               rate=0.1)
+        tid, tick = f"t{i}", s.tick(0)
+        ops = tick.ops
+        if kind == "dense":
+            ops = StackedOperators(dense=jnp.einsum(
+                "mnd,mne->mde", ops.data, ops.data) / n)
+        fleet.join(tid, s.init_W0(), n=n, kind=kind)
+        items[tid] = (ops, tick.U if tid in truths else None)
+    for tid in gone:
+        fleet.leave(tid)
+        del items[tid]
+    (bkt,) = fleet._buckets.values()
+    active = [(s, tid) for s, tid in enumerate(bkt.slots) if tid is not None]
+    assert len(active) < bkt.capacity
+
+    arr, U_b, has_U = fleet._stack(bkt, active, items)
+    want_arr, want_U = _eager_stack(bkt, active, items)
+    assert arr.dtype == want_arr.dtype and U_b.dtype == want_U.dtype
+    np.testing.assert_array_equal(np.asarray(arr), np.asarray(want_arr))
+    np.testing.assert_array_equal(np.asarray(U_b), np.asarray(want_U))
+    assert has_U == {tid: tid in truths for _, tid in active}
+
+
+def test_stack_cold_counts_pools_and_churn_compiles_nothing():
+    """``stack_cold`` counts a bucket pool's first stack launch only, and
+    churn compiles no program: a leave -> join into the freed slot, a
+    leave with no rejoin, a join with other rows into the same bucket,
+    and a tenant that starts giving a truth."""
+    from repro.analysis.retrace import count_compiles
+    m, d, k = 4, 8, 2
+    fleet = TrackerFleet(k=k, T_tick=2, K=2, slots=4, policy=PASSIVE,
+                         topology=erdos_renyi(m, p=0.6, seed=4),
+                         backend="stacked")
+    streams = {n: SlowRotationStream(m=m, d=d, k=k, n_per_agent=n,
+                                     seed=n, rate=0.05)
+               for n in (10, 12, 20)}
+    rows = {"a": 10, "b": 12, "c": 20}         # buckets n_pad 16 and 32
+    truthful = set()
+    for tid, n in rows.items():
+        fleet.join(tid, streams[n].init_W0(), n=n)
+    t = iter(range(100))
+
+    def tick():
+        i, items = next(t), {}
+        for tid in fleet.tenants:
+            s = streams[rows[tid]].tick(i)
+            items[tid] = (s.ops, s.U if tid in truthful else None)
+        return fleet.tick(items).stack_cold
+
+    assert [tick(), tick()] == [2, 0]
+    with count_compiles() as compiles:
+        fleet.leave("b")
+        fleet.join("b", streams[12].init_W0(), n=12)     # the freed slot
+        cold = [tick()]
+        fleet.leave("a")                                 # no rejoin
+        cold.append(tick())
+        rows["a"] = 12                                   # other rows
+        fleet.join("a", streams[12].init_W0(), n=12)
+        cold.append(tick())
+        truthful.add("a")                                # a first truth
+        cold.append(tick())
+        fleet.leave("c")                                 # bucket 2 idle
+        cold.append(tick())
+    assert cold == [0, 0, 0, 0, 0]
+    assert compiles.count == 0, compiles.messages
+    assert fleet.stats["stack_cold"] == 2
+    assert fleet.program_count == 2                    # windows only
+
+
 # ------------------------------------------------------------- guard rails
 def test_tick_requires_exact_tenant_cover():
     m, d, k = 6, 16, 3

@@ -716,10 +716,11 @@ def _fleet_ticks(policy, ticks):
 
 
 def test_fleet_spans_and_counters_name_every_pass(tmp_path):
-    """Under ``fleet.tick`` each bucket's span holds the operator stacking,
-    one ``fleet.window`` per pass with its kind, and the ``fleet.stats``
-    read after each; the tick's report and event count the restarts,
-    escalations and the slot-windows run and kept."""
+    """Under ``fleet.tick`` each bucket's span holds the operator stacking
+    (one stack program, cold on the first tick), one ``fleet.window`` per
+    pass with its kind, and the ``fleet.stats`` read after each; the
+    tick's report and event count the restarts, escalations, the
+    slot-windows run and kept, and the stack's cold launches."""
     from repro.streaming import DriftPolicy
     # the third tick restarts both tenants and escalates them once
     policy = DriftPolicy(jump=1e-9, restart=1e-9, max_escalations=1)
@@ -738,7 +739,8 @@ def test_fleet_spans_and_counters_name_every_pass(tmp_path):
                                     "warm": warm, "form": "gram"}, [])])
 
     def base(warm):
-        return [("fleet.stack", {}, []), window("base", warm),
+        stack = {"stack_cold": int(not warm)}
+        return [("fleet.stack", stack, []), window("base", warm),
                 ("fleet.stats", {}, [])]
 
     restart = base(True) + [
@@ -752,8 +754,8 @@ def test_fleet_spans_and_counters_name_every_pass(tmp_path):
     assert buckets == [[head + (base(False),)], [head + (base(True),)],
                        [head + (restart,)]]
     keys = ("windows", "restarts", "escalations", "slot_windows",
-            "useful_slot_windows")
-    counts = [(1, 0, 0, 4, 2), (1, 0, 0, 4, 2), (3, 2, 2, 12, 6)]
+            "useful_slot_windows", "stack_cold")
+    counts = [(1, 0, 0, 4, 2, 1), (1, 0, 0, 4, 2, 0), (3, 2, 2, 12, 6, 0)]
     assert [tuple(getattr(r, k) for k in keys) for r in reports] == counts
     assert [tuple(e[k] for k in keys)
             for e in rec.of("fleet.tick")] == counts
